@@ -97,6 +97,7 @@ import numpy as np
 
 from benchmarks.common import emit
 from repro import configs
+from repro.launch import compile_cache
 from repro.launch import mesh as mesh_mod
 from repro.models import lm
 from repro.serving.engine import DecodeEngine, Request
@@ -693,29 +694,28 @@ def run_disagg(quick: bool = False):
     stream; the prefill worker decodes zero tokens; disaggregated
     degradation is strictly below colocated.  Reported: T0, T1,
     degradation per topology and the colocated/disagg degradation
-    ratio."""
+    ratio.
+
+    This process stays off JAX's backend until the workers are gone (on
+    a TPU host each worker owns one chip); the single-engine reference
+    runs last."""
     from repro.serving.engine import Router
-    from repro.serving.rpc import EngineProxy
+    from repro.serving.rpc import EngineProxy, worker_chips
 
     arch = "qwen3-next-gdn"
-    cfg, params = arch_setup(arch)
+    cfg = configs.get_arch(arch).reduced()      # workers build the weights
     n_long, max_new = (2, 24) if quick else (2, 48)
     n_storm, plen = (6, 96) if quick else (12, 96)
     kw = dict(max_slots=2, max_len=128, decode_block=2, prefill_chunk=8)
-
-    # single-engine colocated reference: the bitwise target
-    ref_eng = make_engine(cfg, params, **kw)
-    ref = _disagg_longs(n_long, max_new)
-    for r in ref:
-        ref_eng.submit(r)
-    ref_eng.run_until_done()
-    ref_streams = [list(r.output) for r in ref]
+    chips = worker_chips(2)
 
     degradation = {}
+    all_streams = {}
     for mode, roles in (("colocated", ("both", "both")),
                         ("disagg", ("prefill", "decode"))):
-        engines = [EngineProxy(cfg, params_seed=0, role=role, **kw)
-                   for role in roles]
+        engines = [EngineProxy(cfg, params_seed=0, chip=chip, role=role,
+                               **kw)
+                   for chip, role in zip(chips, roles)]
         router = Router(engines)
         # warm-up: compile every program the measured phases touch on
         # every worker (long-session chunk plan + decode on both, the
@@ -740,10 +740,6 @@ def run_disagg(quick: bool = False):
             router.run_until_done()
             assert all(r.done for r in longs + storm)
             streams[phase] = [list(r.output) for r in longs]
-            assert streams[phase] == ref_streams, (
-                f"{mode}/{phase}: disaggregated serving must be "
-                f"bitwise: the handoff restores the exact admit-"
-                f"boundary image")
             tps[phase] = float(np.mean([r.tokens_per_s for r in longs]))
 
         m = router.metrics()
@@ -764,6 +760,22 @@ def run_disagg(quick: bool = False):
              f"bitwise_vs_single_engine;reduced_cpu")
         for e in engines:
             e.shutdown()
+        all_streams[mode] = streams
+
+    # single-engine colocated reference: the bitwise target
+    _, params = arch_setup(arch)
+    ref_eng = make_engine(cfg, params, **kw)
+    ref = _disagg_longs(n_long, max_new)
+    for r in ref:
+        ref_eng.submit(r)
+    ref_eng.run_until_done()
+    ref_streams = [list(r.output) for r in ref]
+    for mode, streams in all_streams.items():
+        for phase, got in streams.items():
+            assert got == ref_streams, (
+                f"{mode}/{phase}: disaggregated serving must be "
+                f"bitwise: the handoff restores the exact admit-"
+                f"boundary image")
 
     assert degradation["disagg"] < degradation["colocated"], (
         f"disaggregation must shield decode from prefill load: "
@@ -824,4 +836,5 @@ if __name__ == "__main__":
                     help="also write per-subcommand machine-readable "
                          "results (name/value/derived records) to PATH")
     args = ap.parse_args()
+    compile_cache.enable()
     run(quick=args.quick, only=args.subcommand, json_path=args.json)

@@ -20,14 +20,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
-
 NEG_INF = -1e30
 
 
 def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
             block_t: int, n_blocks: int, scale: float, window: int | None):
-    t = pl.program_id(2)
+    b, t = pl.program_id(0), pl.program_id(2)
 
     @pl.when(t == 0)
     def _():
@@ -38,7 +36,7 @@ def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
     q = q_ref[0, 0].astype(jnp.float32)               # (Hg, d)
     k = k_ref[0, 0].astype(jnp.float32)               # (Bt, d)
     v = v_ref[0, 0].astype(jnp.float32)               # (Bt, d)
-    length = len_ref[0, 0]                            # scalar int32
+    length = len_ref[b]                               # SMEM scalar
 
     s = scale * jnp.dot(q, k.T, preferred_element_type=jnp.float32)  # (Hg, Bt)
     pos = t * block_t + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
@@ -100,31 +98,32 @@ def attn_decode_pallas(q, k_cache, v_cache, length, *, block_t: int = 256,
         scale = 1.0 / (d ** 0.5)
 
     qg = q.reshape(B, Hkv, Hg, d)
-    len2d = length.reshape(B, 1).astype(jnp.int32)
 
     kern = functools.partial(_kernel, block_t=bt, n_blocks=n_blocks,
                              scale=scale, window=window)
     grid = (B, Hkv, n_blocks)
     o = pl.pallas_call(
         kern,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda b, h, t: (b, 0)),          # length
-            pl.BlockSpec((1, 1, Hg, d), lambda b, h, t: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, bt, d), lambda b, h, t: (b, h, t, 0)),
-            pl.BlockSpec((1, 1, bt, d), lambda b, h, t: (b, h, t, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, Hg, d), lambda b, h, t: (b, h, 0, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,                     # length -> SMEM
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((1, 1, Hg, d), lambda b, h, t, ln: (b, h, 0, 0)),
+                pl.BlockSpec((1, 1, bt, d), lambda b, h, t, ln: (b, h, t, 0)),
+                pl.BlockSpec((1, 1, bt, d), lambda b, h, t, ln: (b, h, t, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, 1, Hg, d),
+                                   lambda b, h, t, ln: (b, h, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((Hg, 1), jnp.float32),
+                pltpu.VMEM((Hg, 1), jnp.float32),
+                pltpu.VMEM((Hg, d), jnp.float32),
+            ]),
         out_shape=jax.ShapeDtypeStruct((B, Hkv, Hg, d), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((Hg, 1), jnp.float32),
-            pltpu.VMEM((Hg, 1), jnp.float32),
-            pltpu.VMEM((Hg, d), jnp.float32),
-        ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=(pltpu.PARALLEL, pltpu.PARALLEL,
                                  pltpu.ARBITRARY)),
         interpret=interpret,
         name=f"attn_decode_bt{bt}",
-    )(len2d, qg, k_cache, v_cache)
+    )(length.reshape(B).astype(jnp.int32), qg, k_cache, v_cache)
     return o.reshape(B, Hq, d)

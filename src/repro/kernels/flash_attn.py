@@ -26,7 +26,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
 
 NEG_INF = -1e30
 
@@ -146,7 +145,7 @@ def flash_fwd(q, k, v, valid_len=None, *, block_q=512, block_kv=512,
             pltpu.VMEM((G, bq), jnp.float32),
             pltpu.VMEM((G, bq, hd), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=(pltpu.PARALLEL, pltpu.PARALLEL,
                                  pltpu.ARBITRARY)),
         interpret=interpret,
@@ -264,7 +263,7 @@ def flash_bwd(q, k, v, o, m, l, do, valid_len=None, *, block_q=512,
         out_specs=pl.BlockSpec((1, G, bq, hd), lambda b, i, j: (b, 0, i, 0)),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((G, bq, hd), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=(pltpu.PARALLEL, pltpu.PARALLEL,
                                  pltpu.ARBITRARY)),
         interpret=interpret,
@@ -299,7 +298,7 @@ def flash_bwd(q, k, v, o, m, l, do, valid_len=None, *, block_q=512,
         ],
         scratch_shapes=[pltpu.VMEM((bk, hd), jnp.float32),
                         pltpu.VMEM((bk, hd), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=(pltpu.PARALLEL, pltpu.PARALLEL,
                                  pltpu.ARBITRARY)),
         interpret=interpret,

@@ -13,9 +13,15 @@ One `pallas_call` per token performs, for every value-head:
 
 Grid: (batch, h_v / head_block).  ``head_block`` is the direct analogue of
 the paper's H_iter design knob (v-heads per dataflow iteration) and is swept
-in the benchmarks.  GVA: q/k blocks hold head_block // n_rep shared heads and
-are broadcast to their value-head pair inside the kernel (the paper's
-paired-head datapath).
+in the benchmarks.  GVA: q/k blocks hold the head_block // n_rep shared heads
+of the block (one head when head_block < n_rep) and are broadcast to their
+value-head group inside the kernel (the paper's paired-head datapath).
+
+Block layout: every per-head row is its own trailing ``(1, d)`` tile and every
+per-head scalar (gate, beta) its own ``(1, 1)`` tile — q/k as (B, Hk, 1, d_k),
+v/o as (B, Hv, 1, d_v), g/beta as (B, Hv, 1, 1).  The last two dims of each
+block then equal the array's, which the TPU lowering requires whatever
+head_block is; the reshapes in the wrapper are free.
 
 ``delta_rule=False`` degenerates to the Mamba-2 / SSD decode update
 (S <- g*S + k v^T, o = S^T q) and is used by the mamba2 architecture.
@@ -29,7 +35,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
+_OUTER = (((0,), (0,)), ((), ()))                 # contract the unit dim:
+                                                  # (1, m) x (1, n) -> (m, n)
 
 
 def _kernel(q_ref, k_ref, v_ref, s_ref, g_ref, b_ref, o_ref, s_out_ref, *,
@@ -37,26 +44,22 @@ def _kernel(q_ref, k_ref, v_ref, s_ref, g_ref, b_ref, o_ref, s_out_ref, *,
     for h in range(head_block):                    # fully unrolled head loop
         hk = h // n_rep                            # shared GVA q/k head
         S = s_ref[0, h].astype(jnp.float32)        # (d_k, d_v) — read pass
-        kk = k_ref[0, hk:hk + 1].astype(jnp.float32)   # (1, d_k)
-        qq = q_ref[0, hk:hk + 1].astype(jnp.float32)   # (1, d_k)
-        g = g_ref[0, h].astype(jnp.float32)
+        kk = k_ref[0, hk].astype(jnp.float32)      # (1, d_k)
+        qq = q_ref[0, hk].astype(jnp.float32)      # (1, d_k)
+        g = g_ref[0, h].astype(jnp.float32)        # (1, 1)
         kq = jnp.concatenate([kk, qq], axis=0)     # (2, d_k)
         rr = jnp.dot(kq, S, preferred_element_type=jnp.float32)  # (2, d_v)
         r, sq = rr[0:1], rr[1:2]                   # (1, d_v) each
+        vv = v_ref[0, h].astype(jnp.float32)       # (1, d_v)
         if delta_rule:
-            beta = b_ref[0, h].astype(jnp.float32)
-            vv = v_ref[0, h:h + 1].astype(jnp.float32)      # (1, d_v)
-            dv = beta * (vv - r)                   # delta correction
-            alpha = jnp.sum(kk * qq)               # q^T k
-            o = scale * (g * sq + alpha * dv)      # fused output correction
+            dv = b_ref[0, h].astype(jnp.float32) * (vv - r)   # delta correction
         else:                                      # SSD / mamba2 path
-            vv = v_ref[0, h:h + 1].astype(jnp.float32)
             dv = vv
-            alpha = jnp.sum(kk * qq)
-            o = scale * (g * sq + alpha * dv)
-        S_new = g * S + jnp.dot(kq[0:1].T, dv,
-                                preferred_element_type=jnp.float32)
-        o_ref[0, h:h + 1] = o.astype(o_ref.dtype)
+        alpha = jnp.sum(kk * qq, axis=1, keepdims=True)       # q^T k, (1, 1)
+        o = scale * (g * sq + alpha * dv)          # fused output correction
+        S_new = g * S + jax.lax.dot_general(
+            kk, dv, _OUTER, preferred_element_type=jnp.float32)
+        o_ref[0, h] = o.astype(o_ref.dtype)
         s_out_ref[0, h] = S_new.astype(s_out_ref.dtype)  # write pass (aliased)
 
 
@@ -77,8 +80,9 @@ def gdn_decode_pallas(q, k, v, S, g, beta, *, head_block: int = 8,
     n_rep = Hv // Hk
     assert Hv % Hk == 0
     hb = min(head_block, Hv)
-    assert Hv % hb == 0 and hb % n_rep == 0, (Hv, hb, n_rep)
-    hbk = hb // n_rep                              # q/k heads per block
+    assert Hv % hb == 0 and (hb % n_rep == 0 or n_rep % hb == 0), \
+        (Hv, hb, n_rep)
+    hbk = max(1, hb // n_rep)                      # q/k heads per block
     if scale is None:
         scale = (1.0 / (d_k ** 0.5)) if delta_rule else 1.0
 
@@ -86,20 +90,22 @@ def gdn_decode_pallas(q, k, v, S, g, beta, *, head_block: int = 8,
     kern = functools.partial(_kernel, head_block=hb, n_rep=n_rep,
                              scale=scale, delta_rule=delta_rule)
     out_shape = [
-        jax.ShapeDtypeStruct((B, Hv, d_v), v.dtype),
+        jax.ShapeDtypeStruct((B, Hv, 1, d_v), v.dtype),
         jax.ShapeDtypeStruct(S.shape, S.dtype),
     ]
+    qk_map = lambda b, i: (b, (i * hb) // (n_rep * hbk), 0, 0)
+    row_map = lambda b, i: (b, i, 0, 0)
     in_specs = [
-        pl.BlockSpec((1, hbk, d_k), lambda b, i: (b, i, 0)),      # q
-        pl.BlockSpec((1, hbk, d_k), lambda b, i: (b, i, 0)),      # k
-        pl.BlockSpec((1, hb, d_v), lambda b, i: (b, i, 0)),       # v
-        pl.BlockSpec((1, hb, d_k, d_v), lambda b, i: (b, i, 0, 0)),  # S
-        pl.BlockSpec((1, hb), lambda b, i: (b, i)),               # g
-        pl.BlockSpec((1, hb), lambda b, i: (b, i)),               # beta
+        pl.BlockSpec((1, hbk, 1, d_k), qk_map),            # q
+        pl.BlockSpec((1, hbk, 1, d_k), qk_map),            # k
+        pl.BlockSpec((1, hb, 1, d_v), row_map),            # v
+        pl.BlockSpec((1, hb, d_k, d_v), row_map),          # S
+        pl.BlockSpec((1, hb, 1, 1), row_map),              # g
+        pl.BlockSpec((1, hb, 1, 1), row_map),              # beta
     ]
     out_specs = [
-        pl.BlockSpec((1, hb, d_v), lambda b, i: (b, i, 0)),
-        pl.BlockSpec((1, hb, d_k, d_v), lambda b, i: (b, i, 0, 0)),
+        pl.BlockSpec((1, hb, 1, d_v), row_map),
+        pl.BlockSpec((1, hb, d_k, d_v), row_map),
     ]
     o, S_new = pl.pallas_call(
         kern,
@@ -108,9 +114,10 @@ def gdn_decode_pallas(q, k, v, S, g, beta, *, head_block: int = 8,
         out_specs=out_specs,
         out_shape=out_shape,
         input_output_aliases={3: 1},               # S updated in place
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=(pltpu.PARALLEL, pltpu.PARALLEL)),
         interpret=interpret,
         name=f"gdn_decode_hb{hb}",
-    )(q, k, v, S, g, beta)
-    return o, S_new
+    )(q[:, :, None], k[:, :, None], v[:, :, None], S,
+      g[:, :, None, None], beta[:, :, None, None])
+    return o.reshape(B, Hv, d_v), S_new

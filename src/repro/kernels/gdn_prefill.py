@@ -26,7 +26,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
+_NT = (((1,), (1,)), ((), ()))                    # a @ b.T
+_TN = (((0,), (0,)), ((), ()))                    # a.T @ b
 
 
 def _nilpotent_inv_apply(A, rhs, chunk):
@@ -40,10 +41,10 @@ def _nilpotent_inv_apply(A, rhs, chunk):
     return X
 
 
-def _kernel(q_ref, k_ref, v_ref, lg_ref, b_ref, s0_ref, o_ref, s_out_ref,
-            s_scr, *, chunk: int, scale: float, delta_rule: bool,
-            n_chunks: int, vl_ref=None):
-    c = pl.program_id(1)
+def _kernel(vl_ref, q_ref, k_ref, v_ref, gc_ref, lr_ref, s0_ref, o_ref,
+            s_out_ref, s_scr, *, chunk: int, scale: float, delta_rule: bool,
+            n_chunks: int):
+    bh, c = pl.program_id(0), pl.program_id(1)
 
     @pl.when(c == 0)
     def _():
@@ -53,37 +54,31 @@ def _kernel(q_ref, k_ref, v_ref, lg_ref, b_ref, s0_ref, o_ref, s_out_ref,
     q = q_ref[0].astype(jnp.float32)                  # (C, d_k)
     k = k_ref[0].astype(jnp.float32)
     v = v_ref[0].astype(jnp.float32)                  # (C, d_v)
-    lg = lg_ref[0].astype(jnp.float32)                # (C,) via (1, C) block
-    b = b_ref[0].astype(jnp.float32)                  # (C,)
-    if vl_ref is not None:
-        # ragged sequence: positions >= valid_len are padding.  Zeroing the
-        # k/v/beta columns and the log-gate contribution makes every padded
-        # token an exact no-op on the state (g=1, rank-1 update 0) and on
-        # every valid output row (their M/A columns vanish), so a fixed-size
-        # masked chunk is provably the same program as a right-sized one.
-        pos = c * chunk + jax.lax.broadcasted_iota(jnp.int32, (chunk, 1), 0)
-        vm = pos < vl_ref[0, 0]                       # (C, 1)
-        k = jnp.where(vm, k, 0.0)
-        v = jnp.where(vm, v, 0.0)
-        lg = jnp.where(vm[:, 0], lg, 0.0)
-        b = jnp.where(vm[:, 0], b, 0.0)
-    L = jnp.cumsum(lg)                                # (C,)
-    L_prev = L - lg
-    gamma = jnp.exp(L)[:, None]
-    gamma_prev = jnp.exp(L_prev)[:, None]
+    # ragged sequence: positions >= valid_len are padding.  The wrapper has
+    # already zeroed their log-gates and betas; zeroing k/v here makes every
+    # padded token an exact no-op on the state (g=1, rank-1 update 0) and on
+    # every valid output row (their M/A columns vanish), so a fixed-size
+    # masked chunk is provably the same program as a right-sized one.
+    pos = c * chunk + jax.lax.broadcasted_iota(jnp.int32, (chunk, 1), 0)
+    vm = pos < vl_ref[bh]                             # (C, 1)
+    k = jnp.where(vm, k, 0.0)
+    v = jnp.where(vm, v, 0.0)
+    gc = gc_ref[0, 0]                                 # (C, 3) columns
+    L, L_prev, beta = gc[:, 0:1], gc[:, 1:2], gc[:, 2:3]
+    L_row = lr_ref[0, 0]                              # (1, C)
+    gamma = jnp.exp(L)                                # (C, 1)
+    gamma_prev = jnp.exp(L_prev)
 
     row = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     col = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
 
-    qk = jnp.dot(q, k.T, preferred_element_type=jnp.float32)
-    decayM = jnp.exp(L[:, None] - L[None, :])
-    M = jnp.where(row >= col, decayM * qk, 0.0)       # inclusive lower
+    qk = jax.lax.dot_general(q, k, _NT, preferred_element_type=jnp.float32)
+    M = jnp.where(row >= col, jnp.exp(L - L_row) * qk, 0.0)   # incl. lower
 
     if delta_rule:
-        beta = b[:, None]                             # (C, 1)
-        kk = jnp.dot(k, k.T, preferred_element_type=jnp.float32)
-        decayA = jnp.exp(L_prev[:, None] - L[None, :])
-        A = jnp.where(row > col, beta * decayA * kk, 0.0)
+        kk = jax.lax.dot_general(k, k, _NT,
+                                 preferred_element_type=jnp.float32)
+        A = jnp.where(row > col, beta * jnp.exp(L_prev - L_row) * kk, 0.0)
         rhs = beta * (v - gamma_prev *
                       jnp.dot(k, S0, preferred_element_type=jnp.float32))
         U = _nilpotent_inv_apply(A, rhs, chunk)
@@ -94,20 +89,14 @@ def _kernel(q_ref, k_ref, v_ref, lg_ref, b_ref, s0_ref, o_ref, s_out_ref,
                  + jnp.dot(M, U, preferred_element_type=jnp.float32))
     o_ref[0] = O.astype(o_ref.dtype)
 
-    w = jnp.exp(L[-1] - L)[:, None]
-    S_new = jnp.exp(L[-1]) * S0 + jnp.dot((w * k).T, U,
-                                          preferred_element_type=jnp.float32)
+    L_end = L_row[:, chunk - 1:chunk]                 # (1, 1)
+    S_new = jnp.exp(L_end) * S0 + jax.lax.dot_general(
+        jnp.exp(L_end - L) * k, U, _TN, preferred_element_type=jnp.float32)
     s_scr[...] = S_new
 
     @pl.when(c == n_chunks - 1)
     def _():
         s_out_ref[0] = S_new.astype(s_out_ref.dtype)
-
-
-def _kernel_ragged(vl_ref, q_ref, k_ref, v_ref, lg_ref, b_ref, s0_ref,
-                   o_ref, s_out_ref, s_scr, **kw):
-    _kernel(q_ref, k_ref, v_ref, lg_ref, b_ref, s0_ref, o_ref, s_out_ref,
-            s_scr, vl_ref=vl_ref, **kw)
 
 
 @functools.partial(
@@ -122,10 +111,17 @@ def gdn_prefill_pallas(q, k, v, log_g, beta, S0, valid_len=None, *,
            the caller index map — see ops.gdn_prefill for the GVA mapping)
     v    : (BH, T, d_v);  log_g, beta: (BH, T);  S0: (BH, d_k, d_v)
     valid_len : optional (BH,) int32 — per-sequence count of real tokens;
-           positions >= valid_len are padding, masked *inside* the kernel so
-           the final state and the valid output rows are exactly those of an
-           unpadded sequence (rows past valid_len are garbage — ignore them).
+           positions >= valid_len are padding, masked so the final state and
+           the valid output rows are exactly those of an unpadded sequence
+           (rows past valid_len are garbage — ignore them).
     Returns O: (BH, T, d_v), S_final: (BH, d_k, d_v).
+
+    The per-token gate terms are small (BH, T) arrays, so the wrapper
+    prepares them in XLA: the in-chunk cumulative log-gate L, its exclusive
+    form L - log_g and beta, laid out as (BH, n_chunks, C, 3) columns plus L
+    as a (BH, n_chunks, 1, C) row.  Every block's last two dims then equal
+    the array's (the TPU tiling rule), and the kernel needs no in-VMEM
+    cumsum or transpose.  ``valid_len`` rides in SMEM (scalar prefetch).
     """
     BH, T, d_k = q.shape
     d_v = v.shape[-1]
@@ -135,41 +131,48 @@ def gdn_prefill_pallas(q, k, v, log_g, beta, S0, valid_len=None, *,
     if scale is None:
         scale = (1.0 / (d_k ** 0.5)) if delta_rule else 1.0
 
+    if valid_len is None:
+        vl = jnp.full((BH,), T, jnp.int32)
+    else:
+        vl = valid_len.reshape(BH).astype(jnp.int32)
+    real = jnp.arange(T)[None, :] < vl[:, None]       # (BH, T)
+    lg = jnp.where(real, log_g.astype(jnp.float32), 0.0)
+    bt = jnp.where(real, beta.astype(jnp.float32), 0.0)
+    lg = lg.reshape(BH, n_chunks, chunk)
+    L = jnp.cumsum(lg, axis=-1)
+    gate_cols = jnp.stack([L, L - lg, bt.reshape(lg.shape)], axis=-1)
+    L_row = L[:, :, None, :]
+
     kern = functools.partial(_kernel, chunk=chunk, scale=scale,
                              delta_rule=delta_rule, n_chunks=n_chunks)
-    grid = (BH, n_chunks)
     out_shape = [
         jax.ShapeDtypeStruct((BH, T, d_v), v.dtype),
         jax.ShapeDtypeStruct((BH, d_k, d_v), S0.dtype),
     ]
     in_specs = [
-        pl.BlockSpec((1, chunk, d_k), lambda b, c: (b, c, 0)),   # q
-        pl.BlockSpec((1, chunk, d_k), lambda b, c: (b, c, 0)),   # k
-        pl.BlockSpec((1, chunk, d_v), lambda b, c: (b, c, 0)),   # v
-        pl.BlockSpec((1, chunk), lambda b, c: (b, c)),           # log_g
-        pl.BlockSpec((1, chunk), lambda b, c: (b, c)),           # beta
-        pl.BlockSpec((1, d_k, d_v), lambda b, c: (b, 0, 0)),     # S0
+        pl.BlockSpec((1, chunk, d_k), lambda b, c, vl: (b, c, 0)),   # q
+        pl.BlockSpec((1, chunk, d_k), lambda b, c, vl: (b, c, 0)),   # k
+        pl.BlockSpec((1, chunk, d_v), lambda b, c, vl: (b, c, 0)),   # v
+        pl.BlockSpec((1, 1, chunk, 3), lambda b, c, vl: (b, c, 0, 0)),
+        pl.BlockSpec((1, 1, 1, chunk), lambda b, c, vl: (b, c, 0, 0)),
+        pl.BlockSpec((1, d_k, d_v), lambda b, c, vl: (b, 0, 0)),     # S0
     ]
-    args = (q, k, v, log_g, beta, S0)
-    if valid_len is not None:
-        kern = functools.partial(_kernel_ragged, chunk=chunk, scale=scale,
-                                 delta_rule=delta_rule, n_chunks=n_chunks)
-        in_specs = [pl.BlockSpec((1, 1), lambda b, c: (b, 0))] + in_specs
-        args = (valid_len.reshape(BH, 1).astype(jnp.int32),) + args
     out_specs = [
-        pl.BlockSpec((1, chunk, d_v), lambda b, c: (b, c, 0)),
-        pl.BlockSpec((1, d_k, d_v), lambda b, c: (b, 0, 0)),
+        pl.BlockSpec((1, chunk, d_v), lambda b, c, vl: (b, c, 0)),
+        pl.BlockSpec((1, d_k, d_v), lambda b, c, vl: (b, 0, 0)),
     ]
     O, S_fin = pl.pallas_call(
         kern,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=out_specs,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(BH, n_chunks),
+            in_specs=in_specs,
+            out_specs=out_specs,
+            scratch_shapes=[pltpu.VMEM((d_k, d_v), jnp.float32)]),
         out_shape=out_shape,
-        scratch_shapes=[pltpu.VMEM((d_k, d_v), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=(pltpu.PARALLEL, pltpu.ARBITRARY)),
         interpret=interpret,
         name=f"gdn_prefill_c{chunk}",
-    )(*args)
+    )(vl, q, k, v, gate_cols, L_row, S0)
     return O, S_fin

@@ -1,9 +1,9 @@
 """Public jit'd entry points for the Pallas kernels.
 
-Backend selection: on a real TPU the kernels run compiled (interpret=False);
-everywhere else (this CPU container, unit tests) they run in interpret mode,
-which executes the same kernel body and BlockSpec pipeline in Python for
-bit-faithful validation against ref.py.
+Backend selection: the platform decides.  On a TPU the kernels run compiled
+(interpret=False); on any other backend (the CPU test runs) they run in
+interpret mode, which executes the same kernel body and BlockSpec pipeline
+in Python for bit-faithful validation against ref.py.
 
 The model code (src/repro/models) calls these through ``use_pallas`` config
 switches; the multi-pod dry-run lowers the algebraically-identical pure-JAX
@@ -20,18 +20,16 @@ from repro.kernels.attn_decode import attn_decode_pallas
 from repro.kernels import ref
 
 
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+def interpret_mode() -> bool:
+    """Interpret the kernels unless the default backend is a TPU."""
+    return jax.default_backend() != "tpu"
 
 
 def gdn_decode(q, k, v, S, g, beta, *, head_block=8, scale=None,
                delta_rule=True, interpret=None):
     """Fused persistent-state GDN decode step (paper Alg. 2)."""
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = interpret_mode()
     return gdn_decode_pallas(q, k, v, S, g, beta, head_block=head_block,
                              scale=scale, delta_rule=delta_rule,
                              interpret=interpret)
@@ -51,7 +49,7 @@ def gdn_prefill(q, k, v, log_g, beta, S0, *, chunk=64, scale=None,
     """
     import jax.numpy as jnp
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = interpret_mode()
     B, T, Hk, d_k = q.shape
     Hv = v.shape[2]
     d_v = v.shape[-1]
@@ -84,9 +82,10 @@ def attn_decode(q, k_cache, v_cache, length, *, block_t=256, scale=None,
                 window=None, interpret=None):
     """Flash-decode GQA attention against a KV cache."""
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = interpret_mode()
     return attn_decode_pallas(q, k_cache, v_cache, length, block_t=block_t,
                               scale=scale, window=window, interpret=interpret)
 
 
-__all__ = ["gdn_decode", "gdn_prefill", "attn_decode", "ref"]
+__all__ = ["gdn_decode", "gdn_prefill", "attn_decode", "interpret_mode",
+           "ref"]

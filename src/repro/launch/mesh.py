@@ -1,4 +1,10 @@
-"""Production meshes.
+"""Device meshes.  ``make_mesh`` is the one constructor every mesh in the
+program and its tests goes through: it owns the axis-type decision.  Every
+axis is ``AxisType.Auto`` (GSPMD propagates shardings from the annotations
+the engine places), which the serving and training code is written for;
+``jax.make_mesh``'s own default builds ``Explicit`` axes, under which the
+embedding gather and ``with_sharding_constraint`` on model-sharded tables
+fail to type-check.
 
 Defined as FUNCTIONS (never module-level constants) so importing this module
 never touches jax device state — required because the dry-run must set
@@ -16,6 +22,7 @@ import math
 from typing import Optional, Sequence, Tuple
 
 import jax
+from jax.sharding import AxisType
 
 
 def validate_mesh_shape(shape: Sequence[int], axes: Sequence[str],
@@ -52,18 +59,22 @@ def validate_mesh_shape(shape: Sequence[int], axes: Sequence[str],
     return shape
 
 
+def make_mesh(shape: Sequence[int], axes: Sequence[str], *,
+              devices: Optional[Sequence] = None):
+    """Mesh of ``shape`` over ``devices`` (default: the first
+    ``prod(shape)`` visible devices), every axis ``Auto``."""
+    devices = list(jax.devices() if devices is None else devices)
+    shape = validate_mesh_shape(shape, axes, device_count=len(devices))
+    return jax.make_mesh(shape, tuple(axes),
+                         devices=devices[:math.prod(shape)],
+                         axis_types=(AxisType.Auto,) * len(shape))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips/pod; multi_pod adds a leading 2-pod axis (512)."""
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    validate_mesh_shape(shape, axes)
-    return jax.make_mesh(shape, axes)
-
-
-def make_local_mesh(data: int = 1, model: int = 1):
-    """Small mesh over locally available devices (tests / examples)."""
-    validate_mesh_shape((data, model), ("data", "model"))
-    return jax.make_mesh((data, model), ("data", "model"))
+    if multi_pod:
+        return make_mesh((2, 16, 16), ("pod", "data", "model"))
+    return make_mesh((16, 16), ("data", "model"))
 
 
 def make_serving_mesh(data: int = 1, model: int = 1):
@@ -72,6 +83,4 @@ def make_serving_mesh(data: int = 1, model: int = 1):
     Uses the first ``data * model`` visible devices (a serving host may
     dedicate the remainder to a second engine behind the router).
     """
-    validate_mesh_shape((data, model), ("data", "model"))
-    devs = jax.devices()[: data * model]
-    return jax.make_mesh((data, model), ("data", "model"), devices=devs)
+    return make_mesh((data, model), ("data", "model"))

@@ -48,21 +48,36 @@ assigns per-engine roles for disaggregated serving, cycled over the
 engines (e.g. ``--roles prefill,decode``): prefill engines pause every
 request at the admit boundary and the router ships the swapped image to
 the least-loaded compatible decode engine — decode ticks never share an
-engine with prefill work, streams stay bitwise the colocated ones.
+engine with prefill work, streams stay bitwise the colocated ones.  The
+launcher process then never initialises JAX's backend: each worker
+builds its weights from ``--seed`` and, on a TPU host, owns one chip
+(more workers than chips is an error).
+
+``--full`` serves the architecture at its published widths (default:
+the reduced CPU smoke config).  Compiled programs persist in
+``$JAX_COMPILATION_CACHE_DIR``, or in ``<checkout>/.jax_cache`` when it
+is unset (``repro.launch.compile_cache``).
+
+Programmatic callers (``chip_smoke.py``) use the same pieces:
+``parse_args`` → ``build`` → ``serve_requests``.
 """
 from __future__ import annotations
 
 import argparse
 import time
+from dataclasses import dataclass
+from typing import Any, List, Optional
 
 import jax
 import numpy as np
 
 from repro import configs
-from repro.configs.base import ServingTopology
+from repro.configs.base import ArchConfig, ServingTopology
+from repro.launch import compile_cache
 from repro.launch import mesh as mesh_mod
 from repro.models import lm
 from repro.serving.engine import DecodeEngine, EngineProxy, Request, Router
+from repro.serving.rpc import worker_chips
 
 
 def _roles(args):
@@ -83,7 +98,7 @@ def build_engines(cfg, params, args, topo: ServingTopology):
     ``--rpc`` each engine is an ``EngineProxy`` worker process instead
     (its own interpreter and jax runtime — real process parallelism);
     weights ship as the init seed, rebuilt bitwise-identically by each
-    worker."""
+    worker, and ``params`` is unused."""
     slots = topo.pad_slots(args.slots)
     if slots != args.slots:
         print(f"slots padded {args.slots} -> {slots} "
@@ -106,21 +121,30 @@ def build_engines(cfg, params, args, topo: ServingTopology):
         host_swap_bytes=args.host_swap_bytes,
         swap_spool_dir=args.swap_spool_dir,
         speculative=args.speculative,
-        draft_cfg=getattr(args, "_draft_cfg", None),
-        draft_params=getattr(args, "_draft_params", None),
+        draft_cfg=_draft_cfg(cfg, args),
         k_draft=args.k_draft,
         adaptive_k=args.adaptive_k)
     engines = []
     dm = topo.devices
     if args.rpc:
+        chips = worker_chips(args.engines)
+        if dm > 1 and chips[0] is not None:
+            raise SystemExit("--rpc on a TPU host runs one chip per worker; "
+                             "serve a --mesh in process instead")
         mesh_shape = None if dm == 1 else topo.shape
+        draft_seed = args.seed + 1 if common["draft_cfg"] else None
         for i in range(args.engines):
-            print(f"spawning worker {i} (role={roles[i]})...")
+            print(f"spawning worker {i} (role={roles[i]}"
+                  + (f", chip {chips[i]}" if chips[i] is not None else "")
+                  + ")...")
             engines.append(EngineProxy(
-                cfg, params_seed=args.seed, role=roles[i],
-                mesh_shape=mesh_shape,
+                cfg, params_seed=args.seed, draft_params_seed=draft_seed,
+                chip=chips[i], role=roles[i], mesh_shape=mesh_shape,
                 mesh_axes=topo.axes if mesh_shape else None, **common))
         return engines, slots
+    if common["draft_cfg"] is not None:
+        common["draft_params"] = lm.init_lm(
+            jax.random.PRNGKey(args.seed + 1), common["draft_cfg"])
     devs = jax.devices()
     shared_note = False
     for i in range(args.engines):
@@ -135,16 +159,27 @@ def build_engines(cfg, params, args, topo: ServingTopology):
                       f"devices 0..{dm - 1} with engine 0 (only "
                       f"{len(devs)} visible) — correct, but they "
                       f"time-slice the same hardware")
-        mesh_mod.validate_mesh_shape(topo.shape, topo.axes,
-                                     device_count=len(sl))
         mesh = (None if dm == 1 and args.engines == 1 else
-                jax.make_mesh(topo.shape, topo.axes, devices=sl))
+                mesh_mod.make_mesh(topo.shape, topo.axes, devices=sl))
         engines.append(DecodeEngine(cfg, params, mesh=mesh,
                                     role=roles[i], **common))
     return engines, slots
 
 
-def main():
+def _draft_cfg(cfg: ArchConfig, args) -> Optional[ArchConfig]:
+    """The speculative draft's config (``None``: none, or self-draft)."""
+    if not args.speculative or args.draft_config == "self":
+        return None
+    dcfg = configs.get_arch(args.draft_config)
+    if args.reduced:
+        dcfg = dcfg.reduced()
+    if dcfg.vocab != cfg.vocab:
+        raise SystemExit(f"--draft-config {args.draft_config}: vocab "
+                         f"{dcfg.vocab} != target vocab {cfg.vocab}")
+    return dcfg
+
+
+def parse_args(argv: Optional[List[str]] = None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--requests", type=int, default=8)
@@ -276,31 +311,71 @@ def main():
                     help="device nucleus sampling (1.0 = disabled)")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--reduced", action="store_true", default=True)
-    ap.add_argument("--full", dest="reduced", action="store_false")
-    args = ap.parse_args()
+    ap.add_argument("--full", dest="reduced", action="store_false",
+                    help="published widths instead of the reduced config")
+    args = ap.parse_args(argv)
     if args.workers is not None:
         args.rpc = True
         args.engines = args.workers
+    return args
 
+
+@dataclass
+class Served:
+    """What ``build`` returns: the router over its engines, plus the
+    config and (in-process engines only) the parameters they serve."""
+    cfg: ArchConfig
+    params: Any
+    topo: ServingTopology
+    engines: list
+    router: Router
+    slots: int
+
+
+def build(args, *, cfg: Optional[ArchConfig] = None,
+          params: Any = None) -> Served:
+    """Config, weights (from ``--seed``), engines and router for ``args``.
+
+    ``cfg``/``params`` replace the ones ``args`` names, for callers that
+    serve one set of weights under several configs.  With ``--rpc`` the
+    workers build their own weights and this process never initialises
+    JAX's backend.
+    """
     topo = ServingTopology.parse(args.mesh,
                                  staging_depth=args.staging_depth)
-    cfg = configs.get_arch(args.arch)
-    if args.reduced:
-        cfg = cfg.reduced()
-    params = lm.init_lm(jax.random.PRNGKey(args.seed), cfg)
-    args._draft_cfg = args._draft_params = None
-    if args.speculative and args.draft_config != "self":
-        dcfg = configs.get_arch(args.draft_config)
+    if cfg is None:
+        cfg = configs.get_arch(args.arch)
         if args.reduced:
-            dcfg = dcfg.reduced()
-        if dcfg.vocab != cfg.vocab:
-            raise SystemExit(f"--draft-config {args.draft_config}: vocab "
-                             f"{dcfg.vocab} != target vocab {cfg.vocab}")
-        args._draft_cfg = dcfg
-        args._draft_params = lm.init_lm(jax.random.PRNGKey(args.seed + 1),
-                                        dcfg)
+            cfg = cfg.reduced()
+    if params is None and not args.rpc:
+        params = lm.init_lm(jax.random.PRNGKey(args.seed), cfg)
     engines, slots = build_engines(cfg, params, args, topo)
-    router = Router(engines, policy=args.router_policy)
+    return Served(cfg, params, topo, engines,
+                  Router(engines, policy=args.router_policy), slots)
+
+
+def serve_requests(served: Served, args):
+    """Submit ``--requests`` seeded random prompts (4–16 tokens) and
+    serve them to completion.  Returns (finished requests, seconds)."""
+    rng = np.random.default_rng(args.seed)
+    for i in range(args.requests):
+        prompt = rng.integers(1, served.cfg.vocab, size=rng.integers(4, 17),
+                              dtype=np.int32)
+        served.router.submit(Request(rid=i, prompt=prompt,
+                                     max_new_tokens=args.max_new,
+                                     temperature=args.temperature,
+                                     top_k=args.top_k, top_p=args.top_p))
+    t0 = time.perf_counter()
+    done = served.router.run_until_done()
+    return done, time.perf_counter() - t0
+
+
+def main(argv: Optional[List[str]] = None):
+    compile_cache.enable()
+    args = parse_args(argv)
+    served = build(args)
+    cfg, topo, slots = served.cfg, served.topo, served.slots
+    engines, router = served.engines, served.router
     eng = engines[0]
     # per-slot budgets straight from the mixers' declarative cache specs
     print(f"topology: {args.engines} "
@@ -344,17 +419,7 @@ def main():
               f"draft state "
               f"({ex.speculative_bytes / 2**20:.2f} MiB total, from "
               f"checkpoint_spec)")
-    rng = np.random.default_rng(args.seed)
-    for i in range(args.requests):
-        prompt = rng.integers(1, cfg.vocab, size=rng.integers(4, 17),
-                              dtype=np.int32)
-        router.submit(Request(rid=i, prompt=prompt,
-                              max_new_tokens=args.max_new,
-                              temperature=args.temperature,
-                              top_k=args.top_k, top_p=args.top_p))
-    t0 = time.perf_counter()
-    done = router.run_until_done()
-    dt = time.perf_counter() - t0
+    done, dt = serve_requests(served, args)
     m = router.metrics()
     print(f"served {m['requests']} requests, {m['tokens']} tokens in "
           f"{dt:.2f}s ({m['tokens'] / dt:.1f} tok/s) over "
@@ -399,6 +464,7 @@ def main():
     if args.rpc:
         for e in engines:
             e.shutdown()
+    return done, m
 
 
 if __name__ == "__main__":

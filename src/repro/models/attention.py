@@ -122,9 +122,10 @@ def attn_train(p, x, *, rope_theta=10000.0, window=None, block_kv=512,
     q, k, v = _qkv(p, x, positions, rope_theta)
     if use_flash_kernel:
         # Pallas fused path: scores stay in VMEM, HBM traffic O(B·T·H·d)
+        from repro.kernels import ops
         from repro.kernels.flash_attn import flash_attention
         o = flash_attention(q, k, v, min(512, T), min(512, T), window,
-                            jax.default_backend() != "tpu")
+                            ops.interpret_mode())
     else:
         o = blockwise_attention(q, k, v, window=window, block_kv=block_kv)
     o = _apply_head_mask(o, head_mask)

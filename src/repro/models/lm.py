@@ -115,7 +115,10 @@ def init_lm(key, cfg: ArchConfig):
             for i, kind in enumerate(kinds):
                 per_pos[i].append(_init_layer(next(layer_keys), kind, cfg,
                                               dtype))
-        gparams.append([_stack(ps) for ps in per_pos])
+        # stack one position at a time, dropping its per-layer arrays as
+        # it goes: at full width, keeping them all alive until the last
+        # stack holds twice the weights in device memory
+        gparams.append([_stack(per_pos.pop(0)) for _ in kinds])
     params["groups"] = gparams
     return params
 

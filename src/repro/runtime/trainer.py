@@ -35,6 +35,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.checkpoint import manager as ckpt
 from repro.configs.base import ArchConfig
 from repro.data.pipeline import DataConfig, HostDataLoader
+from repro.launch.mesh import make_mesh
 from repro.models import lm
 from repro.optim import optimizers as opt
 from repro.parallel import sharding
@@ -65,8 +66,7 @@ class TrainerConfig:
 
 def make_data_mesh() -> Mesh:
     """Elastic 1-D data mesh over whatever devices are currently present."""
-    devs = np.array(jax.devices())
-    return Mesh(devs.reshape(len(devs), 1), ("data", "model"))
+    return make_mesh((jax.device_count(), 1), ("data", "model"))
 
 
 def make_schedule(tc: TrainerConfig) -> Callable:

@@ -38,14 +38,23 @@ caller) and marks requests whose state lived in the dead process as
 ``"failed"``.
 
 Weights cross the boundary as a **seed** when possible
-(``params_seed`` → the worker rebuilds ``lm.init_lm(PRNGKey(seed),
-cfg)``, deterministic across processes) and as host numpy otherwise.
-No timeouts are imposed on replies — a first step may sit behind
-minutes of XLA compilation; death is detected by EOF, not silence.
+(``params_seed`` / ``draft_params_seed`` → the worker rebuilds
+``lm.init_lm(PRNGKey(seed), cfg)``, deterministic across processes) and
+as host numpy otherwise.  No timeouts are imposed on replies — a first
+step may sit behind minutes of XLA compilation; death is detected by
+EOF, not silence.
+
+One process per chip: a TPU chip belongs to the one process that opened
+it, so a launcher that serves through workers never initialises JAX's
+backend itself.  ``worker_chips`` asks a short-lived child process what
+the host has, and ``EngineProxy(chip=i)`` limits its worker to chip
+``i`` through libtpu's environment before the worker imports JAX.
 """
 from __future__ import annotations
 
+import os
 import selectors
+import socket
 import subprocess
 import sys
 from typing import Any, Dict, List, Optional, Tuple
@@ -69,6 +78,48 @@ _EXC: Dict[str, type] = {
 
 class WorkerDied(RuntimeError):
     """The engine worker process is gone (EOF/broken pipe mid-call)."""
+
+
+_PROBE = "import jax; print(jax.default_backend(), jax.device_count())"
+
+
+def worker_chips(n_workers: int, python: str = sys.executable
+                 ) -> List[Optional[int]]:
+    """Chip index for each of ``n_workers`` one-chip workers (``None``
+    each off a TPU, where workers share the host's CPU backend).
+
+    The platform and chip count come from a child process that exits
+    before any worker starts, so the caller never holds a chip.  More
+    workers than chips is a ``ValueError``.
+    """
+    out = subprocess.run([python, "-c", _PROBE], check=True,
+                         capture_output=True, text=True).stdout
+    platform, count = out.split()[-2:]
+    if platform != "tpu":
+        return [None] * n_workers
+    if n_workers > int(count):
+        raise ValueError(
+            f"{n_workers} engine workers need one TPU chip each, but this "
+            f"host has {count}")
+    return list(range(n_workers))
+
+
+def _free_port() -> int:
+    with socket.socket() as sock:
+        sock.bind(("localhost", 0))
+        return sock.getsockname()[1]
+
+
+def _chip_env(chip: int) -> Dict[str, str]:
+    """libtpu settings that give a process chip ``chip`` alone, as a
+    one-chip slice of its own (and never a CPU fallback)."""
+    port = _free_port()
+    return {"JAX_PLATFORMS": "tpu",
+            "TPU_VISIBLE_CHIPS": str(chip),
+            "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_PORT": str(port),
+            "TPU_PROCESS_ADDRESSES": f"localhost:{port}"}
 
 
 def _hostify(tree):
@@ -110,18 +161,24 @@ class EngineWorker:
         import jax
         from repro.serving.scheduler import Scheduler
 
+        from repro.models import lm
+
         cfg = init["cfg"]
         if init.get("params_seed") is not None:
-            from repro.models import lm
             params = lm.init_lm(jax.random.PRNGKey(init["params_seed"]),
                                 cfg)
         else:
             params = init["params"]
         kwargs = dict(init.get("kwargs") or {})
+        if init.get("draft_params_seed") is not None:
+            kwargs["draft_params"] = lm.init_lm(
+                jax.random.PRNGKey(init["draft_params_seed"]),
+                kwargs["draft_cfg"])
         mesh_shape = init.get("mesh_shape")
         if mesh_shape is not None:
+            from repro.launch.mesh import make_mesh
             axes = tuple(init.get("mesh_axes") or ("data", "model"))
-            kwargs["mesh"] = jax.make_mesh(tuple(mesh_shape), axes)
+            kwargs["mesh"] = make_mesh(tuple(mesh_shape), axes)
         self.eng = Scheduler(cfg, params, **kwargs)
         return {"max_len": self.eng.max_len, "role": self.eng.role,
                 "max_slots": self.eng.max_slots}
@@ -236,9 +293,13 @@ class EngineProxy:
     the pipelined ``step_begin``/``step_drain`` pair the router uses to
     tick workers concurrently.  Constructor args mirror ``Scheduler``
     — pass ``params_seed`` instead of params when the weights are a
-    deterministic init (cheap to ship, bitwise-identical on rebuild)."""
+    deterministic init (cheap to ship, bitwise-identical on rebuild), and
+    ``draft_params_seed`` likewise for a speculative draft.  ``chip``
+    (from ``worker_chips``) limits the worker to that one TPU chip."""
 
     def __init__(self, cfg, params=None, *, params_seed: Optional[int] = None,
+                 draft_params_seed: Optional[int] = None,
+                 chip: Optional[int] = None,
                  mesh_shape=None, mesh_axes=None,
                  python: str = sys.executable, **engine_kwargs):
         if (params is None) == (params_seed is None):
@@ -256,14 +317,16 @@ class EngineProxy:
                 and engine_kwargs["draft_params"] is not None:
             engine_kwargs["draft_params"] = _hostify(
                 engine_kwargs["draft_params"])
+        env = None if chip is None else {**os.environ, **_chip_env(chip)}
         self.proc = subprocess.Popen(
             [python, "-m", "repro.serving.rpc"],
-            stdin=subprocess.PIPE, stdout=subprocess.PIPE)
+            stdin=subprocess.PIPE, stdout=subprocess.PIPE, env=env)
         self._sel = selectors.DefaultSelector()
         self._sel.register(self.proc.stdout, selectors.EVENT_READ)
         init = {"cfg": cfg,
                 "params": None if params is None else _hostify(params),
                 "params_seed": params_seed,
+                "draft_params_seed": draft_params_seed,
                 "kwargs": engine_kwargs,
                 "mesh_shape": (tuple(mesh_shape)
                                if mesh_shape is not None else None),

@@ -332,6 +332,7 @@ SUBPROCESS_TEST = textwrap.dedent("""
     import numpy as np
     from repro import configs
     from repro.models import lm
+    from repro.launch.mesh import make_mesh
     from repro.serving.engine import DecodeEngine, Request
 
     cfg = configs.get_arch("qwen3-next-gdn").reduced()
@@ -357,9 +358,9 @@ SUBPROCESS_TEST = textwrap.dedent("""
     #        batched == per-prompt == 1-device baseline, greedy and
     #        stochastic, at a dividing (8) and a non-dividing (2,
     #        row-replicated) staging depth
-    mesh1 = jax.make_mesh((1, 1), ("data", "model"),
-                          devices=jax.devices()[:1])
-    mesh8 = jax.make_mesh((8, 1), ("data", "model"))
+    mesh1 = make_mesh((1, 1), ("data", "model"),
+                      devices=jax.devices()[:1])
+    mesh8 = make_mesh((8, 1), ("data", "model"))
     for stochastic in (False, True):
         _, base = serve(mesh1, None, stochastic)
         _, per8 = serve(mesh8, False, stochastic)
@@ -388,7 +389,7 @@ SUBPROCESS_TEST = textwrap.dedent("""
 
     # --- 3. head-sharded (4, 2): batched serving completes (model-axis
     #        psum ordering, checked at completion like any TP stack)
-    mesh42 = jax.make_mesh((4, 2), ("data", "model"))
+    mesh42 = make_mesh((4, 2), ("data", "model"))
     eng42, out42 = serve(mesh42, None, False, depth=4)
     assert eng42.prefill_batching
     assert all(len(o) == 4 + i for i, o in enumerate(out42))

@@ -14,6 +14,7 @@ import pytest
 from repro import configs
 from repro.configs.base import SHAPES, shape_applicable
 from repro.launch import steps as steps_mod
+from repro.launch.mesh import make_mesh
 
 
 def test_input_specs_all_cells():
@@ -45,7 +46,7 @@ def test_long500k_skips_are_exactly_the_full_attention_archs():
 
 
 def test_microbatch_sizing():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     cfg = configs.get_arch("arctic-480b")
     mb = steps_mod.microbatches_for(cfg, SHAPES["train_4k"], mesh)
     assert mb >= 1
@@ -64,7 +65,8 @@ SUB = textwrap.dedent("""
     from repro import configs
     from repro.configs.base import ShapeConfig
     from repro.launch import steps as sm, hlo_cost
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((4, 2), ("data", "model"))
     cfg = configs.get_arch("qwen3-next-gdn")
     # small cell: decode against a 2k cache, batch 8
     shape = ShapeConfig("mini_decode", 2048, 8, "decode")

@@ -84,7 +84,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 import sys
 sys.path.insert(0, "src")
 from repro.launch import hlo_cost
-mesh = jax.make_mesh((8,), ("model",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((8,), ("model",))
 w = jax.ShapeDtypeStruct((4, 256, 256), jnp.float32)
 x = jax.ShapeDtypeStruct((128, 256), jnp.float32)
 def f(x, w):

@@ -27,10 +27,14 @@ PARITY_ARCHS = ["yi-9b", "h2o-danube-1.8b", "qwen3-next-gdn", "mamba2-1.3b",
 
 def _rollout(cfg, B=2, T=8):
     """The exact computation the goldens were dumped with (seed tree,
-    tests/golden/README.md)."""
-    params = lm.init_lm(jax.random.PRNGKey(0), cfg)
-    tokens = jax.random.randint(jax.random.PRNGKey(1), (B, T + 1), 0,
-                                cfg.vocab)
+    tests/golden/README.md).  Params and tokens are drawn with the
+    non-partitionable threefry stream the goldens were made under; JAX
+    0.5 made the partitionable stream the default, which draws different
+    numbers from the same key."""
+    with jax.threefry_partitionable(False):
+        params = lm.init_lm(jax.random.PRNGKey(0), cfg)
+        tokens = jax.random.randint(jax.random.PRNGKey(1), (B, T + 1), 0,
+                                    cfg.vocab)
     caches = lm.init_caches(cfg, B, max_len=32)
     logits_p, caches = lm.prefill(params, cfg, caches, tokens=tokens[:, :T])
     logits_d, _ = lm.decode_step(params, cfg, tokens[:, T], caches)
@@ -41,13 +45,17 @@ def _rollout(cfg, B=2, T=8):
 
 @pytest.mark.parametrize("arch", PARITY_ARCHS)
 def test_golden_parity_vs_pre_refactor(arch):
-    """prefill + decode_step logits are bitwise identical to the dispatch-
-    chain implementation the registry replaced (goldens dumped at the seed
-    commit)."""
+    """prefill + decode_step logits match the dispatch-chain implementation
+    the registry replaced (goldens dumped at the seed commit).  The check
+    was bitwise on the JAX the goldens were dumped with; XLA's float32 CPU
+    numerics have drifted since (max |diff| 2.5e-6 on JAX 0.9.0), so it is
+    a float32 tolerance now."""
     golden = np.load(GOLDEN)
     logits_p, logits_d = _rollout(configs.get_arch(arch).reduced())
-    np.testing.assert_array_equal(logits_p, golden[f"{arch}/prefill"])
-    np.testing.assert_array_equal(logits_d, golden[f"{arch}/decode"])
+    np.testing.assert_allclose(logits_p, golden[f"{arch}/prefill"],
+                               rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(logits_d, golden[f"{arch}/decode"],
+                               rtol=1e-5, atol=1e-5)
 
 
 def test_gdn_naive_matches_fused():

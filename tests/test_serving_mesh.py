@@ -323,6 +323,7 @@ SUBPROCESS_TEST = textwrap.dedent("""
     from repro import configs
     from repro.models import lm
     from repro.parallel import sharding as rules
+    from repro.launch.mesh import make_mesh
     from repro.serving.engine import DecodeEngine, Request
 
     cfg = configs.get_arch("qwen3-next-gdn").reduced()
@@ -344,9 +345,9 @@ SUBPROCESS_TEST = textwrap.dedent("""
         return eng, [list(r.output) for r in reqs]
 
     # --- 1. bitwise parity: 1-device mesh == 8-device data-sharded mesh
-    mesh1 = jax.make_mesh((1, 1), ("data", "model"),
-                          devices=jax.devices()[:1])
-    mesh8 = jax.make_mesh((8, 1), ("data", "model"))
+    mesh1 = make_mesh((1, 1), ("data", "model"),
+                      devices=jax.devices()[:1])
+    mesh8 = make_mesh((8, 1), ("data", "model"))
     for stochastic in (False, True):
         _, base = serve(mesh1, stochastic)
         eng8, out8 = serve(mesh8, stochastic)
@@ -356,7 +357,7 @@ SUBPROCESS_TEST = textwrap.dedent("""
 
     # --- 2. buffer placement: slot axis on data, state heads / KV
     #        context on model
-    mesh42 = jax.make_mesh((4, 2), ("data", "model"))
+    mesh42 = make_mesh((4, 2), ("data", "model"))
     eng42, out42 = serve(mesh42, False)
 
     def ax(entry):          # normalize a PartitionSpec entry to a tuple
@@ -440,6 +441,7 @@ SUBPROCESS_PAGING_TEST = textwrap.dedent("""
     import numpy as np
     from repro import configs
     from repro.models import lm
+    from repro.launch.mesh import make_mesh
     from repro.serving.engine import DecodeEngine, Request
 
     cfg = configs.get_arch("qwen3-next-gdn").reduced()
@@ -480,10 +482,10 @@ SUBPROCESS_PAGING_TEST = textwrap.dedent("""
         assert all(q.done for q in rr)
         return eng, [list(q.output) for q in rr]
 
-    mesh1 = jax.make_mesh((1, 1), ("data", "model"),
-                          devices=jax.devices()[:1])
-    mesh4 = jax.make_mesh((4, 1), ("data", "model"),
-                          devices=jax.devices()[:4])
+    mesh1 = make_mesh((1, 1), ("data", "model"),
+                      devices=jax.devices()[:1])
+    mesh4 = make_mesh((4, 1), ("data", "model"),
+                      devices=jax.devices()[:4])
 
     # --- 1. bitwise parity: pause/resume on a 1-device mesh AND a
     #        4-device data-sharded mesh both reproduce the uninterrupted
@@ -537,6 +539,7 @@ SUBPROCESS_ASYNC_PAGING_TEST = textwrap.dedent("""
     import numpy as np
     from repro import configs
     from repro.models import lm
+    from repro.launch.mesh import make_mesh
     from repro.serving.engine import DecodeEngine, Request
 
     cfg = configs.get_arch("qwen3-next-gdn").reduced()
@@ -576,10 +579,10 @@ SUBPROCESS_ASYNC_PAGING_TEST = textwrap.dedent("""
         assert all(q.done for q in rr)
         return eng, [list(q.output) for q in rr]
 
-    mesh1 = jax.make_mesh((1, 1), ("data", "model"),
-                          devices=jax.devices()[:1])
-    mesh4 = jax.make_mesh((4, 1), ("data", "model"),
-                          devices=jax.devices()[:4])
+    mesh1 = make_mesh((1, 1), ("data", "model"),
+                      devices=jax.devices()[:1])
+    mesh4 = make_mesh((4, 1), ("data", "model"),
+                      devices=jax.devices()[:4])
 
     # --- 1. bitwise parity: ASYNC pause/resume on a 1-device mesh and a
     #        4-device data-sharded mesh both reproduce the synchronous
@@ -657,6 +660,7 @@ SUBPROCESS_SPEC_TEST = textwrap.dedent("""
     import numpy as np
     from repro import configs
     from repro.models import lm
+    from repro.launch.mesh import make_mesh
     from repro.serving.engine import DecodeEngine, Request
 
     cfg = configs.get_arch("qwen3-next-gdn").reduced()
@@ -679,10 +683,10 @@ SUBPROCESS_SPEC_TEST = textwrap.dedent("""
         assert all(q.done for q in rr)
         return eng, [list(q.output) for q in rr]
 
-    mesh1 = jax.make_mesh((1, 1), ("data", "model"),
-                          devices=jax.devices()[:1])
-    mesh4 = jax.make_mesh((4, 1), ("data", "model"),
-                          devices=jax.devices()[:4])
+    mesh1 = make_mesh((1, 1), ("data", "model"),
+                      devices=jax.devices()[:1])
+    mesh4 = make_mesh((4, 1), ("data", "model"),
+                      devices=jax.devices()[:4])
 
     # --- 1. bitwise parity: data-sharded speculative streams == the
     #        1-device non-speculative streams, greedy AND stochastic

@@ -1,0 +1,104 @@
+"""The serving kernels compile for a TPU v5e at real widths.
+
+Interpret mode (every other kernel test) runs the kernel bodies in
+Python and never sees the TPU lowering's rules: block shapes whose last
+two dims are not (8, 128)-aligned or equal to the array's, scalar
+loads, VMEM limits.  These tests compile each kernel with the TPU
+compiler for a described, unattached ``v5e:2x2`` chip — no device runs
+anything — at the widths of qwen3-next-gdn (GDN B=4, Hk=16, Hv=32,
+d=128; attention Hq=16, Hkv=2, d=128) and mamba2-1.3b (the
+``delta_rule=False`` path: Hk=1, Hv=64, d_k=128, d_v=64).
+
+The topology is described inside a module fixture, never at import:
+only one process may load the TPU library, and pytest-xdist workers all
+import this file.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.attn_decode import attn_decode_pallas
+from repro.kernels.gdn_decode import gdn_decode_pallas
+from repro.kernels.gdn_prefill import gdn_prefill_pallas
+
+BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
+
+QWEN = dict(Hk=16, Hv=32, dk=128, dv=128, delta_rule=True)
+MAMBA = dict(Hk=1, Hv=64, dk=128, dv=64, delta_rule=False)
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    """A described-topology compile is written to the persistent cache
+    but cannot be read back without a chip; keep it out."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def topo(no_persistent_cache):
+    from jax.experimental import topologies
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile(fn, sharding, *shapes):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    print(compiled.memory_analysis())
+    return compiled
+
+
+@pytest.mark.parametrize("widths,head_block", [
+    (QWEN, 2), (QWEN, 8), (QWEN, 32), (MAMBA, 8)])
+def test_gdn_decode_compiles(one_chip, widths, head_block):
+    B, Hk, Hv, dk, dv = 4, widths["Hk"], widths["Hv"], widths["dk"], \
+        widths["dv"]
+    _compile(lambda q, k, v, S, g, b: gdn_decode_pallas(
+        q, k, v, S, g, b, head_block=head_block,
+        delta_rule=widths["delta_rule"]), one_chip,
+        ((B, Hk, dk), BF16), ((B, Hk, dk), BF16), ((B, Hv, dv), BF16),
+        ((B, Hv, dk, dv), F32), ((B, Hv), F32), ((B, Hv), F32))
+
+
+@pytest.mark.parametrize("widths,T,chunk,ragged", [
+    (QWEN, 128, 64, False), (QWEN, 128, 64, True),
+    (QWEN, 16, 16, True),              # the serving engine's prefill chunk
+    (MAMBA, 128, 64, True)])
+def test_gdn_prefill_compiles(one_chip, widths, T, chunk, ragged):
+    BH = 2 * widths["Hv"]
+    dk, dv = widths["dk"], widths["dv"]
+    shapes = [((BH, T, dk), BF16), ((BH, T, dk), BF16), ((BH, T, dv), BF16),
+              ((BH, T), F32), ((BH, T), F32), ((BH, dk, dv), F32)]
+    if ragged:
+        shapes.append(((BH,), I32))
+    _compile(lambda *a: gdn_prefill_pallas(
+        *a, chunk=chunk, delta_rule=widths["delta_rule"]), one_chip,
+        *shapes)
+
+
+@pytest.mark.parametrize("window", [None, 512])
+def test_attn_decode_compiles(one_chip, window):
+    B, Hq, Hkv, T, d = 4, 16, 2, 1024, 128
+    _compile(lambda q, k, v, n: attn_decode_pallas(
+        q, k, v, n, block_t=256, window=window), one_chip,
+        ((B, Hq, d), BF16), ((B, Hkv, T, d), BF16), ((B, Hkv, T, d), BF16),
+        ((B,), I32))
