@@ -31,6 +31,12 @@ Entry points:
                                             -> k fused decode+sample steps
                                                (one host sync per k tokens)
 
+The cached paths name their layers for the profiler: each mixer call runs
+under ``jax.named_scope("mixer_<kind>")``, each FFN under ``"ffn"``, and
+the final norm, head and sampler under ``"head_sample"``.  A scope only
+labels the operations (their HLO ``op_name``); the computation is the
+same.
+
 VLM / audio archs: the modality frontend is a stub per the assignment —
 ``embeds`` (precomputed patch/frame embeddings, (B, T, d_model)) are fed
 directly in place of token embeddings.
@@ -268,16 +274,20 @@ def _run_cached(params, cfg: ArchConfig, x, caches, mode: str,
                 lp = lp_slice[i]
                 mixer = get_mixer(kind)
                 h = layers.rmsnorm_fwd(lp["norm1"], x, cfg.norm_eps)
-                if mode == "prefill":
-                    mix, nc = mixer.prefill(lp["mixer"], cfg, h, c_slice[i])
-                elif mode == "chunk":
-                    mix, nc = mixer.prefill_chunk(lp["mixer"], cfg, h,
-                                                  c_slice[i],
-                                                  valid_len=valid_len)
-                else:
-                    mix, nc = mixer.decode(lp["mixer"], cfg, h, c_slice[i])
+                with jax.named_scope(f"mixer_{kind}"):
+                    if mode == "prefill":
+                        mix, nc = mixer.prefill(lp["mixer"], cfg, h,
+                                                c_slice[i])
+                    elif mode == "chunk":
+                        mix, nc = mixer.prefill_chunk(lp["mixer"], cfg, h,
+                                                      c_slice[i],
+                                                      valid_len=valid_len)
+                    else:
+                        mix, nc = mixer.decode(lp["mixer"], cfg, h,
+                                               c_slice[i])
                 x = x + mix
-                x, _ = _ffn_fwd(cfg, lp, x, decode=(mode == "decode"))
+                with jax.named_scope("ffn"):
+                    x, _ = _ffn_fwd(cfg, lp, x, decode=(mode == "decode"))
                 x = _constrain(x, dp_axes)
                 new_c.append(nc)
             return x, new_c
@@ -390,8 +400,9 @@ def prefill_sample(params, cfg: ArchConfig, caches, sampler, sample_fn,
         else:
             idx = jnp.maximum(vl - 1, 0)[:, None, None]        # (B, 1, 1)
             h_last = jnp.take_along_axis(x, idx, axis=1)[:, 0]
-    h = layers.rmsnorm_fwd(params["final_norm"], h_last, cfg.norm_eps)
-    tok, sampler = sample_fn(sampler, _logits(params, cfg, h))
+    with jax.named_scope("head_sample"):
+        h = layers.rmsnorm_fwd(params["final_norm"], h_last, cfg.norm_eps)
+        tok, sampler = sample_fn(sampler, _logits(params, cfg, h))
     return tok.astype(jnp.int32), sampler, caches
 
 
@@ -401,8 +412,9 @@ def decode_step(params, cfg: ArchConfig, tokens_t, caches, dp_axes=None):
     x = _constrain(x.astype(jnp.dtype(cfg.act_dtype)), dp_axes)
     x, caches = _run_cached(params, cfg, x, caches, "decode",
                             dp_axes=dp_axes)
-    x = layers.rmsnorm_fwd(params["final_norm"], x, cfg.norm_eps)
-    return _logits(params, cfg, x), caches
+    with jax.named_scope("head_sample"):
+        x = layers.rmsnorm_fwd(params["final_norm"], x, cfg.norm_eps)
+        return _logits(params, cfg, x), caches
 
 
 def _greedy_sample(sampler, logits):
@@ -444,8 +456,9 @@ def decode_steps(params, cfg: ArchConfig, tokens, caches, k: int,
         toks, cs, st = carry
         live = ~st["done"]
         logits, cs = decode_step(params, cfg, toks, cs, dp_axes=dp_axes)
-        nxt, st = sample_fn(st, logits)
-        nxt = jnp.where(live, nxt, toks)
+        with jax.named_scope("head_sample"):
+            nxt, st = sample_fn(st, logits)
+            nxt = jnp.where(live, nxt, toks)
         return (nxt, cs, st), (nxt, live)
 
     (tokens, caches, sampler), (toks, valid) = jax.lax.scan(

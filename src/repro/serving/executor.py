@@ -50,7 +50,15 @@ touches an accelerator buffer lives here; everything that touches a
   shape; ``compiled_programs()`` reports the live cache per family.  The
   masked planner bounds the prefill families at O(1) shapes per prompt
   (≤ _MAX_SCAN_CHUNKS scan lengths + 1 admit shape ever); the pow2
-  baseline needs O(log chunk) tail programs on top.
+  baseline needs O(log chunk) tail programs on top.  Every program of a
+  family shares one name, ``serve_<family>`` (``decode``,
+  ``prefill_scan``, ``prefill_chunk``, ``admit``, ``scatter``,
+  ``gather``, ``staging_zeros`` and the speculative ``draft``,
+  ``verify``, ``draft_prefill``), so a device trace shows its XLA module
+  as ``jit_serve_<family>`` whatever the static shape.  The decode
+  dispatch and every host read of a program's result run under
+  ``serve:`` spans (``repro.serving.spans``) carrying the scheduler's
+  tick.
 
 **Mesh sharding.**  With ``mesh`` set (a ``("data", "model")`` device
 mesh, see ``launch/mesh.py``), every buffer above is allocated with a
@@ -67,6 +75,8 @@ their placement across ticks.
 """
 from __future__ import annotations
 
+import functools
+import time
 import warnings
 from collections import deque
 from typing import Any, Deque, Dict, List, NamedTuple, Optional, Tuple
@@ -78,7 +88,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ArchConfig
 from repro.models import lm
-from repro.serving import sampling
+from repro.serving import sampling, spans
 
 
 class PlanStep(NamedTuple):
@@ -241,6 +251,24 @@ def _scatter_fn(caches, sampler, tokens, staging, row, tok, slot):
     tokens = jax.lax.dynamic_update_slice(
         tokens, tok.astype(tokens.dtype), (slot,))
     return caches, sampler, tokens
+
+
+class _Program:
+    """One jitted serving program.  Its first call compiles it (or loads
+    it from the persistent cache) under a ``serve:compile`` span."""
+
+    __slots__ = ("fn", "name", "warm")
+
+    def __init__(self, fn, name: str):
+        self.fn, self.name, self.warm = fn, name, False
+
+    def __call__(self, *args):
+        if self.warm:
+            return self.fn(*args)
+        with spans.span("compile", program=self.name):
+            out = self.fn(*args)
+        self.warm = True
+        return out
 
 
 class DeviceExecutor:
@@ -461,7 +489,7 @@ class DeviceExecutor:
         # staging ring (prefill overlap targets); the sampler rows are
         # produced by the fused admit program, not materialized up front
         self._staging_zeros = self._jit(
-            lambda: lm.init_caches(cfg, 1, max_len),
+            "staging_zeros", lambda: lm.init_caches(cfg, 1, max_len),
             out_sh=self._sh_staging)
         self.staging: List[Any] = [self._staging_zeros()
                                    for _ in range(staging_depth)]
@@ -503,10 +531,15 @@ class DeviceExecutor:
         self.gather_ring = gather_ring
         self._gather_free: Deque[int] = deque(range(gather_ring))
         self._gather_pending: Dict[int, PendingSwap] = {}
+        # the scheduler's tick, carried by the decode spans; and the host
+        # seconds spent blocked in the programs' result reads (the
+        # ``serve:*.sync`` spans)
+        self.tick = 0
+        self.sync_s = 0.0
         # donate only the slot buffers: the staging pytree's (repeats, 1,
         # ...) leaves have no same-shape output to alias (XLA would warn)
         self._scatter_p = self._jit(
-            _scatter_fn, donate=(0, 1, 2),
+            "scatter", _scatter_fn, donate=(0, 1, 2),
             in_sh=(self._sh_caches, self._sh_sampler, self._sh_tokens,
                    self._sh_staging, self._sh_row, self._sh_rep,
                    self._sh_rep),
@@ -547,18 +580,25 @@ class DeviceExecutor:
         self._sh_rep = NamedSharding(mesh, P())
         self._sh_toks2d = NamedSharding(mesh, P(None, *tok_spec))
 
-    def _jit(self, fn, *, donate=(), in_sh=None, out_sh=None):
+    def _jit(self, family: str, fn, *, donate=(), in_sh=None,
+             out_sh=None) -> _Program:
         """jit with explicit in/out shardings when running under a mesh
         (every program is one SPMD program over the whole mesh), plain
-        jit otherwise."""
-        if self.mesh is None:
-            return jax.jit(fn, donate_argnums=donate)
+        jit otherwise.  The program is named ``serve_<family>``, so its
+        XLA module (and its events in a device trace) is
+        ``jit_serve_<family>`` whatever its static shape."""
+        name = f"serve_{family}"
+
+        @functools.wraps(fn)
+        def program(*args):
+            return fn(*args)
+        program.__name__ = program.__qualname__ = name
         kw = {}
-        if in_sh is not None:
+        if self.mesh is not None and in_sh is not None:
             kw["in_shardings"] = in_sh
-        if out_sh is not None:
+        if self.mesh is not None and out_sh is not None:
             kw["out_shardings"] = out_sh
-        return jax.jit(fn, donate_argnums=donate, **kw)
+        return _Program(jax.jit(program, donate_argnums=donate, **kw), name)
 
     def _zeros(self, spec, shardings):
         if self.mesh is None:
@@ -683,6 +723,7 @@ class DeviceExecutor:
             kw = "embeds" if is_embeds else "tokens"
             if masked:
                 prog = self._jit(
+                    "prefill_scan",
                     lambda p, t, vl, c, kw=kw: lm.prefill_chunk_scan(
                         p, self.cfg, c, valid_lens=vl, **{kw: t}),
                     donate=(3,),
@@ -691,6 +732,7 @@ class DeviceExecutor:
                     out_sh=self._sh_staging)
             else:
                 prog = self._jit(
+                    "prefill_scan",
                     lambda p, t, c, kw=kw: lm.prefill_chunk_scan(
                         p, self.cfg, c, **{kw: t}),
                     donate=(2,),
@@ -713,6 +755,7 @@ class DeviceExecutor:
         if prog is None:
             kw = "embeds" if is_embeds else "tokens"
             prog = self._jit(
+                "prefill_chunk",
                 lambda p, t, c, kw=kw: lm.prefill_chunk(
                     p, self.cfg, c, **{kw: t})[1],
                 donate=(2,),
@@ -760,7 +803,7 @@ class DeviceExecutor:
                 n_rep = 7
 
             prog = self._jit(
-                _admit, donate=(2,),
+                "admit", _admit, donate=(2,),
                 in_sh=((self._sh_params, self._sh_rep, self._sh_staging)
                        + self._rep_sh(n_rep)
                        if self.mesh is not None else None),
@@ -841,7 +884,7 @@ class DeviceExecutor:
         }
         self._bseed = np.int32(0)
         self._bscatter_p = self._jit(
-            _bscatter_fn, donate=(0, 1, 2, 3),
+            "scatter", _bscatter_fn, donate=(0, 1, 2, 3),
             in_sh=(self._sh_caches, self._sh_sampler, self._sh_tokens,
                    self._sh_bstaging, self._sh_bsampler, self._sh_btoks,
                    self._sh_rep, self._sh_rep),
@@ -893,6 +936,7 @@ class DeviceExecutor:
         if prog is None:
             kw = "embeds" if is_embeds else "tokens"
             prog = self._jit(
+                "prefill_scan",
                 lambda p, t, v, c, kw=kw: lm.prefill_chunk_scan(
                     p, self.cfg, c, valid_lens=v, **{kw: t}),
                 donate=(3,),
@@ -952,7 +996,7 @@ class DeviceExecutor:
                 return toks, samp, c
 
             prog = self._jit(
-                _badmit, donate=(2, 3, 4),
+                "admit", _badmit, donate=(2, 3, 4),
                 in_sh=((self._sh_params, self._sh_rep, self._sh_bstaging,
                         self._sh_bsampler, self._sh_btoks)
                        + self._rep_sh(9)
@@ -969,6 +1013,24 @@ class DeviceExecutor:
             self._bargs["rid"], self._bargs["temperature"],
             self._bargs["top_k"], self._bargs["top_p"],
             self._bargs["eos_id"], self._bargs["budget"])
+
+    def _sync(self, phase: str, *arrays):
+        """Read ``arrays`` to the host: the wait for the device, under a
+        ``serve:<phase>.sync`` span and counted in ``sync_s``."""
+        t0 = time.perf_counter()
+        with spans.span(f"{phase}.sync", tick=self.tick):
+            out = tuple(np.asarray(a) for a in arrays)
+        self.sync_s += time.perf_counter() - t0
+        return out
+
+    def admit_tokens(self) -> np.ndarray:
+        """The batched staging rows' first tokens, on the host (the wait
+        for the batched admit)."""
+        return self._sync("prefill", self.btoks)[0]
+
+    def admit_token(self, buf: int) -> int:
+        """Ring buffer ``buf``'s first token, on the host."""
+        return int(self._sync("prefill", self.staging_tok[buf])[0][0])
 
     def bscatter(self, assigns, release_rows=()):
         """Admit every finished staging row into its slot in ONE donated
@@ -1023,7 +1085,7 @@ class DeviceExecutor:
         perturb the eventual ``harvest``."""
         if self._gather_p is None:
             self._gather_p = self._jit(
-                _gather_fn, donate=(1,),
+                "gather", _gather_fn, donate=(1,),
                 in_sh=(self._sh_caches, self._sh_sampler, self._sh_tokens,
                        self._sh_rep),
                 out_sh=((self._sh_staging, self._sh_row, self._sh_rep,
@@ -1063,7 +1125,7 @@ class DeviceExecutor:
         self._ensure_batched()
         if self._bgather_p is None:
             self._bgather_p = self._jit(
-                _bgather_fn,
+                "gather", _bgather_fn,
                 in_sh=(self._sh_bstaging, self._sh_bsampler,
                        self._sh_btoks, self._sh_rep),
                 out_sh=((self._sh_staging, self._sh_row, self._sh_rep)
@@ -1158,6 +1220,7 @@ class DeviceExecutor:
         prog = self._draft_p.get(k)
         if prog is None:
             prog = self._jit(
+                "draft",
                 lambda dp, t, dc, s, k=k: lm.decode_steps(
                     dp, self.draft_cfg, t, dc, k,
                     sampler=s, sample_fn=sampling.sample)[0],
@@ -1165,8 +1228,9 @@ class DeviceExecutor:
                        self._sh_dcaches, self._sh_sampler),
                 out_sh=self._sh_toks2d)
             self._draft_p[k] = prog
-        return prog(self.draft_params, self.tokens, self.dcaches,
-                    self.sampler)
+        with spans.span("draft.dispatch", tick=self.tick):
+            return prog(self.draft_params, self.tokens, self.dcaches,
+                        self.sampler)
 
     def spec_verify(self, k: int, dtoks):
         """Score a pending k-token draft with ``lm.verify_steps`` and
@@ -1189,7 +1253,7 @@ class DeviceExecutor:
                 return toks, valid, last, com, run, dcom, drun, st
 
             prog = self._jit(
-                _verify, donate=(3, 4, 5, 6, 7, 8),
+                "verify", _verify, donate=(3, 4, 5, 6, 7, 8),
                 in_sh=(self._sh_params, self._sh_dparams, self._sh_toks2d,
                        self._sh_tokens, self._sh_caches, self._sh_ckpt,
                        self._sh_dcaches, self._sh_dckpt, self._sh_sampler),
@@ -1199,12 +1263,13 @@ class DeviceExecutor:
                          self._sh_sampler)
                         if self.mesh is not None else None))
             self._verify_p[k] = prog
-        (toks, valid, self.tokens, self.caches, self.ckpt, self.dcaches,
-         self.dckpt, self.sampler) = prog(
-            self.params, self.draft_params, dtoks, self.tokens,
-            self.caches, self.ckpt, self.dcaches, self.dckpt,
-            self.sampler)
-        return np.asarray(toks), np.asarray(valid)
+        with spans.span("decode.dispatch", tick=self.tick):
+            (toks, valid, self.tokens, self.caches, self.ckpt,
+             self.dcaches, self.dckpt, self.sampler) = prog(
+                self.params, self.draft_params, dtoks, self.tokens,
+                self.caches, self.ckpt, self.dcaches, self.dckpt,
+                self.sampler)
+        return self._sync("decode", toks, valid)
 
     def draft_prefill_slot(self, slot: int, tokens_1d):
         """Rebuild slot ``slot``'s draft-model state from the request's
@@ -1241,7 +1306,7 @@ class DeviceExecutor:
                     dcaches, c1)
 
             prog = self._jit(
-                _dprefill, donate=(3,),
+                "draft_prefill", _dprefill, donate=(3,),
                 in_sh=(self._sh_dparams, self._sh_rep, self._sh_rep,
                        self._sh_dcaches, self._sh_rep),
                 out_sh=self._sh_dcaches)
@@ -1290,6 +1355,7 @@ class DeviceExecutor:
         prog = self._decode_p.get(k)
         if prog is None:
             prog = self._jit(
+                "decode",
                 lambda p, t, c, s, k=k: lm.decode_steps(
                     p, self.cfg, t, c, k,
                     sampler=s, sample_fn=sampling.sample),
@@ -1300,6 +1366,7 @@ class DeviceExecutor:
                          self._sh_caches, self._sh_sampler)
                         if self.mesh is not None else None))
             self._decode_p[k] = prog
-        toks, valid, self.tokens, self.caches, self.sampler = prog(
-            self.params, self.tokens, self.caches, self.sampler)
-        return np.asarray(toks), np.asarray(valid)
+        with spans.span("decode.dispatch", tick=self.tick):
+            toks, valid, self.tokens, self.caches, self.sampler = prog(
+                self.params, self.tokens, self.caches, self.sampler)
+        return self._sync("decode", toks, valid)

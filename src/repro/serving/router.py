@@ -453,7 +453,8 @@ class Router:
         """Aggregate metrics over all live engines: counters summed,
         per-request means weighted by each engine's completed-request
         count, plus the per-engine dicts and the router's own placement
-        counters."""
+        counters.  Each engine's tick and prefill logs stay in its own
+        dict: tick ids are per engine."""
         per = []
         for i, eng in enumerate(self.engines):
             if i in self._dead:
@@ -473,8 +474,6 @@ class Router:
         decoded = sum(m["decoded_tokens"] for m in per)
         return {
             "engines": len(self.engines),
-            "policy": self.policy,
-            "roles": [self._role(e) for e in self.engines],
             "requests": sum(n),
             "tokens": sum(m["tokens"] for m in per),
             "ticks": sum(m["ticks"] for m in per),
@@ -502,12 +501,10 @@ class Router:
                                             for m in per),
             "swap_harvests_forced": sum(m["swap_harvests_forced"]
                                         for m in per),
-            "draining_swaps": sum(m["draining_swaps"] for m in per),
             "spills": sum(m["spills"] for m in per),
             "spill_loads": sum(m["spill_loads"] for m in per),
             "spill_bytes": sum(m["spill_bytes"] for m in per),
             "handoffs_out": sum(m["handoffs_out"] for m in per),
-            "handoffs_pending": sum(m["handoffs"] for m in per),
             "speculative": int(all(m["speculative"] for m in per)),
             "spec_ticks": sum(m["spec_ticks"] for m in per),
             "drafted_tokens": sum(m["drafted_tokens"] for m in per),
@@ -521,11 +518,12 @@ class Router:
             "mean_ttft_s": wmean("mean_ttft_s"),
             "mean_latency_s": wmean("mean_latency_s"),
             "mean_tokens_per_s": wmean("mean_tokens_per_s"),
+            "steps": sum(m["steps"] for m in per),
+            "sched_self_s": sum(m["sched_self_s"] for m in per),
+            "compiles": sum(m["compiles"] for m in per),
             "placed": list(self.placed),
             "migrated": self.migrated,
             "handoffs": self.handoffs,
-            "rehomed": self.rehomed,
-            "draining": sorted(self._draining),
             "dead": sorted(self._dead),
             "per_engine": per,
         }

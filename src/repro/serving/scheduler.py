@@ -56,6 +56,19 @@ topology-blind; only the buffers underneath it are distributed.
 Wall-clock metrics (TTFT, latency, throughput) are stamped per request;
 ``metrics()`` aggregates them plus the decode-only µs/token that
 ``benchmarks/bench_serving.py`` sweeps.
+
+Tracing is always on.  Each ``step`` is a tick with a serial id; its
+phases run under ``serve:`` host spans (``repro.serving.spans``) that a
+running profiler records on the device trace's clock: ``serve:step``
+holds ``serve:admit`` (with ``serve:scatter``, ``serve:prefill.dispatch``
+and ``serve:prefill.sync``), ``serve:decode.dispatch``,
+``serve:decode.sync``, ``serve:emit`` and the paging sweeps that are on
+(``serve:paging.*``).  ``metrics()`` adds a bounded log of the decode
+ticks (length, live slots and summed contexts per step) and of the
+prefill dispatches (program, and per row rid, start and valid tokens),
+the scheduler's own host time (``sched_self_s`` over ``steps``: each step
+less its ``*.sync`` waits) and the programs compiled or loaded inside
+``step`` (``compiles``).
 """
 from __future__ import annotations
 
@@ -70,7 +83,7 @@ import jax
 import numpy as np
 
 from repro.configs.base import ArchConfig
-from repro.serving import wire
+from repro.serving import spans, wire
 from repro.serving.executor import (DeviceExecutor, PendingSwap, PlanStep,
                                     SwappedState)
 
@@ -91,6 +104,9 @@ SWAPPED, RESUMING, DONE = "swapped", "resuming", "done"
 # file in the spool dir
 DRAINING, HOSTED = "draining", "hosted"
 PREFETCHED, SPILLED = "prefetched", "spilled"
+
+# entries kept by each of the scheduler's tick and prefill logs
+LOG_LEN = 8192
 
 
 @dataclass
@@ -399,6 +415,19 @@ class Scheduler:
         self.spill_bytes = 0        # bytes written to disk
         self._metrics_seen: set = set()  # id() of requests already
                                     # counted before reset_metrics
+        # tracing: a serial tick id that reset_metrics leaves alone (the
+        # spans and logs carry it); per decode tick its length and, per
+        # step, the live slots and their summed contexts; per prefill
+        # dispatch its program and rows (rid, start position, valid
+        # tokens); the host time of ``step`` less its waits for the
+        # device (``serve:*.sync``); programs compiled or loaded in step
+        self._tick = 0
+        self.tick_log: Deque[dict] = deque(maxlen=LOG_LEN)
+        self.prefill_log: Deque[dict] = deque(maxlen=LOG_LEN)
+        self.sched_self_s = 0.0
+        self.steps = 0
+        self.compiles = 0
+        spans.install()
 
     # ---------------------------------------------------- compat surface
     @property
@@ -930,7 +959,8 @@ class Scheduler:
             self.swap_s += t1 - t0
             self.swap_stall_s += t1 - t0
             self.swap_put_s += t1 - t0
-        self.executor.restore_slot(slot, rec.state, prestaged=prestaged)
+        with spans.span("scatter", tick=self._tick):
+            self.executor.restore_slot(slot, rec.state, prestaged=prestaged)
         self.scatter_dispatches += 1
         now = time.perf_counter()
         self.swap_s += now - t1
@@ -1105,19 +1135,33 @@ class Scheduler:
             top_k=req.top_k, top_p=req.top_p, eos_id=req.eos_id,
             budget=req.max_new_tokens)
 
+    def _prefill(self, program: str, rows: list, dispatch, *args):
+        """One prefill dispatch of ``program``, logged with its rows
+        ``(rid, start position, valid tokens)`` and run under a
+        ``serve:prefill.dispatch`` span."""
+        self.prefill_log.append({"tick": self._tick, "program": program,
+                                 "rows": rows})
+        with spans.span("prefill.dispatch", tick=self._tick,
+                        program=program):
+            dispatch(*args)
+        self.stage_dispatches += 1
+
     def _stage_dispatch_one(self, st: _Staging):
         step = st.plan[st.plan_pos]
         chunk = st.req._inputs[st.prompt_pos:st.prompt_pos + step.tokens]
+        rows = [(st.req.rid, st.prompt_pos, step.tokens)]
+        ex = self.executor
         if step.kind == "scan":
-            self.executor.stage_chunk_scan(st.buf, chunk,
-                                           valid_lens=step.valid)
+            self._prefill("prefill_scan", rows, ex.stage_chunk_scan,
+                          st.buf, chunk, step.valid)
         elif step.kind == "chunk":
-            self.executor.stage_chunk(st.buf, chunk)
+            self._prefill("prefill_chunk", rows, ex.stage_chunk, st.buf,
+                          chunk)
         else:
-            self.executor.stage_admit(st.buf, chunk, valid_len=step.valid)
+            self._prefill("admit", rows, ex.stage_admit, st.buf, chunk,
+                          step.valid)
         st.prompt_pos += step.tokens
         st.plan_pos += 1
-        self.stage_dispatches += 1
 
     def _stage_finish(self, st: _Staging):
         """Plan complete: sync the fused first token (this is the
@@ -1126,7 +1170,7 @@ class Scheduler:
         max_new_tokens=1, never occupying a slot) or hold it staged-ready
         until a slot frees."""
         req = st.req
-        tok = int(np.asarray(self.executor.staging_tok[st.buf])[0])
+        tok = self.executor.admit_token(st.buf)
         req.t_first = time.perf_counter()
         req.output.append(tok)
         if self._finished(req, tok):
@@ -1145,7 +1189,8 @@ class Scheduler:
     def _stage_scatter(self):
         st = self._stagings.pop(0)
         slot = self.free.popleft()
-        self.executor.scatter(slot, st.buf)
+        with spans.span("scatter", tick=self._tick):
+            self.executor.scatter(slot, st.buf)
         self.scatter_dispatches += 1
         self._free_bufs.append(st.buf)
         self.active[slot] = st.req
@@ -1182,7 +1227,8 @@ class Scheduler:
         dirty (finished-at-admit) rows; released rows return to the free
         pool clean."""
         rows = [row for _, row in assigns]
-        self.executor.bscatter(assigns, self._dirty_rows)
+        with spans.span("scatter", tick=self._tick):
+            self.executor.bscatter(assigns, self._dirty_rows)
         self.scatter_dispatches += 1
         for row in rows:
             self._free_bufs.append(row)
@@ -1195,7 +1241,7 @@ class Scheduler:
         token from the SAME device-confirmed read and stamps the SAME
         ``t_first`` — a batch admit is one device event, so serial
         per-entry stamps would skew TTFT for all but the first row."""
-        toks = np.asarray(self.executor.btoks)      # the one host sync
+        toks = self.executor.admit_tokens()         # the one host sync
         now = time.perf_counter()
         for st in sts:
             req = st.req
@@ -1229,6 +1275,8 @@ class Scheduler:
         stream — is bitwise that of per-prompt dispatch."""
         scan_e: Dict[bool, list] = {}
         admit_e: Dict[bool, list] = {}
+        scan_rows: Dict[bool, list] = {}
+        admit_rows: Dict[bool, list] = {}
         admitted: List[_Staging] = []
         for st in self._stagings:
             if st.ready or st.admitted:
@@ -1243,6 +1291,8 @@ class Scheduler:
                                        st.prompt_pos + take * C]
                 scan_e.setdefault(is_embeds, []).append(
                     (st.buf, chunk, take))
+                scan_rows.setdefault(is_embeds, []).append(
+                    (st.req.rid, st.prompt_pos, take * C))
                 st.prompt_pos += take * C
                 st.chunks_left -= take
                 budget -= take
@@ -1251,16 +1301,18 @@ class Scheduler:
                                        st.prompt_pos + st.tail]
                 admit_e.setdefault(is_embeds, []).append(
                     (st.buf, chunk, st.tail))
+                admit_rows.setdefault(is_embeds, []).append(
+                    (st.req.rid, st.prompt_pos, st.tail))
                 st.prompt_pos += st.tail
                 st.admitted = True
                 admitted.append(st)
                 budget -= 1
-        for entries in scan_e.values():
-            self.executor.bstage_chunk_scan(entries)
-            self.stage_dispatches += 1
-        for entries in admit_e.values():
-            self.executor.bstage_admit(entries)
-            self.stage_dispatches += 1
+        for kind, entries in scan_e.items():
+            self._prefill("prefill_scan", scan_rows[kind],
+                          self.executor.bstage_chunk_scan, entries)
+        for kind, entries in admit_e.items():
+            self._prefill("admit", admit_rows[kind],
+                          self.executor.bstage_admit, entries)
         if admitted:
             self._stage_finish_batch(admitted)
         return bool(scan_e or admit_e)
@@ -1453,25 +1505,26 @@ class Scheduler:
             self.spec_ticks += 1
             self.drafted_tokens += k * len(live)
             tick_accepted = 0
-            for slot, req in list(self.active.items()):
-                emitted = 0
-                for j in range(toks.shape[0]):
-                    if not valid[j, slot]:
-                        break
-                    tok = int(toks[j, slot])
-                    req.output.append(tok)
-                    self.decoded_tokens += 1
-                    emitted += 1
-                    if self._finished(req, tok):
-                        req.done = True
-                        req.state = DONE
-                        req.t_done = now
-                        del self.active[slot]
-                        self.free.append(slot)
-                        break
-                # every emission beyond the first rode on an accepted
-                # draft token (the first is the verify's own sample)
-                tick_accepted += max(emitted - 1, 0)
+            with spans.span("emit", tick=self._tick):
+                for slot, req in list(self.active.items()):
+                    emitted = 0
+                    for j in range(toks.shape[0]):
+                        if not valid[j, slot]:
+                            break
+                        tok = int(toks[j, slot])
+                        req.output.append(tok)
+                        self.decoded_tokens += 1
+                        emitted += 1
+                        if self._finished(req, tok):
+                            req.done = True
+                            req.state = DONE
+                            req.t_done = now
+                            del self.active[slot]
+                            self.free.append(slot)
+                            break
+                    # every emission beyond the first rode on an accepted
+                    # draft token (the first is the verify's own sample)
+                    tick_accepted += max(emitted - 1, 0)
             self.accepted_tokens += tick_accepted
             if self.adaptive_k and k > 0:
                 self._adapt_k(tick_accepted, k * len(live))
@@ -1482,15 +1535,7 @@ class Scheduler:
                                  if r.rid == rid), None)
                     if slot is not None:    # may have finished in verify
                         self._swap_out_active(slot, resume=res)
-        if self.async_paging and self._draining_q:
-            self._harvest_sweep()
-        if self.swap_spool_dir is not None:
-            self._apply_spill()
-        if self.swap_policy != "manual":
-            self._apply_swap_policy()
-        self._admit()
-        if self.async_paging:
-            self._prefetch_resume()
+        self._boundary()
         if not self.active:
             return
         k = self._spec_k()
@@ -1500,6 +1545,24 @@ class Scheduler:
         self._pending = (k, dtoks,
                          [r.rid for r in self.active.values()])
 
+    def _boundary(self):
+        """The tick boundary before the decode dispatch: the paging
+        sweeps that are on, then the admit pipeline."""
+        if self.async_paging and self._draining_q:
+            with spans.span("paging.harvest"):
+                self._harvest_sweep()
+        if self.swap_spool_dir is not None:
+            with spans.span("paging.spill"):
+                self._apply_spill()
+        if self.swap_policy != "manual":
+            with spans.span("paging.policy"):
+                self._apply_swap_policy()
+        with spans.span("admit", tick=self._tick):
+            self._admit()
+        if self.async_paging:
+            with spans.span("paging.prefetch"):
+                self._prefetch_resume()
+
     def step(self):
         """One engine tick: advance the admit pipeline (free slots fill as
         in the serialized baseline, plus up to ``staging_depth``
@@ -1508,19 +1571,26 @@ class Scheduler:
         for the decode block.
 
         Speculative engines run the draft–verify tick instead (see
-        ``_step_speculative``); the non-speculative path below is
-        untouched."""
-        if self.speculative:
-            return self._step_speculative()
-        if self.async_paging and self._draining_q:
-            self._harvest_sweep()
-        if self.swap_spool_dir is not None:
-            self._apply_spill()
-        if self.swap_policy != "manual":
-            self._apply_swap_policy()
-        self._admit()
-        if self.async_paging:
-            self._prefetch_resume()
+        ``_step_speculative``).  Either runs under a ``serve:step`` span
+        carrying a new tick id; the step's host time less its waits for
+        the device adds to ``sched_self_s``, and the programs it compiled
+        or loaded to ``compiles``."""
+        self._tick += 1
+        self.executor.tick = self._tick
+        compiles, waits = spans.compiles(), self.executor.sync_s
+        t0 = time.perf_counter()
+        with spans.span("step", tick=self._tick):
+            if self.speculative:
+                self._step_speculative()
+            else:
+                self._step_decode()
+        self.sched_self_s += (time.perf_counter() - t0
+                              - (self.executor.sync_s - waits))
+        self.steps += 1
+        self.compiles += spans.compiles() - compiles
+
+    def _step_decode(self):
+        self._boundary()
         if not self.active:
             return
         k = self._tick_k()
@@ -1529,20 +1599,28 @@ class Scheduler:
         now = time.perf_counter()
         self.decode_s += now - t0
         self.ticks += 1
-        for slot, req in list(self.active.items()):
-            for j in range(toks.shape[0]):
-                if not valid[j, slot]:
-                    break
-                tok = int(toks[j, slot])
-                req.output.append(tok)
-                self.decoded_tokens += 1
-                if self._finished(req, tok):
-                    req.done = True
-                    req.state = DONE
-                    req.t_done = now
-                    del self.active[slot]
-                    self.free.append(slot)
-                    break
+        # the live slots entering each step and their summed contexts
+        live, ctx = [0] * toks.shape[0], [0] * toks.shape[0]
+        with spans.span("emit", tick=self._tick):
+            for slot, req in list(self.active.items()):
+                pos = req.prompt_len + len(req.output)
+                for j in range(toks.shape[0]):
+                    if not valid[j, slot]:
+                        break
+                    live[j] += 1
+                    ctx[j] += pos + j
+                    tok = int(toks[j, slot])
+                    req.output.append(tok)
+                    self.decoded_tokens += 1
+                    if self._finished(req, tok):
+                        req.done = True
+                        req.state = DONE
+                        req.t_done = now
+                        del self.active[slot]
+                        self.free.append(slot)
+                        break
+        self.tick_log.append({"tick": self._tick, "k": k, "live": live,
+                              "ctx": ctx})
 
     def run_until_done(self, max_ticks: int = 10_000, *,
                        strict: bool = True) -> List[Request]:
@@ -1604,6 +1682,11 @@ class Scheduler:
         self.accepted_tokens = 0
         self.draft_prefills = 0
         self.handoffs_out = 0
+        self.tick_log.clear()
+        self.prefill_log.clear()
+        self.sched_self_s = 0.0
+        self.steps = 0
+        self.compiles = 0
         self._metrics_seen = {id(r) for r in self._all if r.done}
 
     def metrics(self) -> Dict[str, float]:
@@ -1660,7 +1743,6 @@ class Scheduler:
                 self.swap_harvests_overlapped
                 / max(1, self.swap_harvests_overlapped
                       + self.swap_harvests_forced)),
-            "draining_swaps": len(self._draining_q),
             "spills": self.spills,
             "spill_loads": self.spill_loads,
             "spill_bytes": self.spill_bytes,
@@ -1698,4 +1780,9 @@ class Scheduler:
             "mean_ttft_s": float(np.mean(ttfts)) if ttfts else 0.0,
             "mean_latency_s": float(np.mean(lats)) if lats else 0.0,
             "mean_tokens_per_s": float(np.mean(tps)) if tps else 0.0,
+            "steps": self.steps,
+            "sched_self_s": self.sched_self_s,
+            "compiles": self.compiles,
+            "tick_log": list(self.tick_log),
+            "prefill_log": list(self.prefill_log),
         }
