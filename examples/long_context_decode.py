@@ -1,7 +1,7 @@
 """Long-context decode with O(1) state — the paper's regime at scale.
 
 Decodes with a mamba2 (SSD) model far past any window/cache size: the
-recurrent state is a fixed (heads, d_state, d_head) tensor per layer no
+recurrent state is a fixed (heads, d_head, d_state) tensor per layer no
 matter how long the context grows — contrast with the full-attention archs
 whose KV cache would grow linearly (and which therefore skip the 500k cell,
 see DESIGN.md).  Also demonstrates state-consistency: decoding T tokens

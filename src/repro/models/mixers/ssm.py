@@ -58,10 +58,13 @@ class SSD(SequenceMixer):
 
     @classmethod
     def cache_spec(cls, cfg, batch, max_len):
+        """``S`` is (batch, nheads, headdim, d_state): d_state minor, so a
+        TPU tile holds 128 state values with no lane padding (see
+        ``repro.models.ssm.SSMState``)."""
         nheads = cfg.ssm_d_inner // cfg.ssm_headdim
         act = jnp.dtype(cfg.act_dtype)
         return CacheSpec(ssm_layer.SSMState(
-            S=ArraySpec((batch, nheads, cfg.ssm_d_state, cfg.ssm_headdim),
+            S=ArraySpec((batch, nheads, cfg.ssm_headdim, cfg.ssm_d_state),
                         jnp.dtype(cfg.state_dtype), "state"),
             conv_x=ArraySpec((batch, _CONV_W - 1, cfg.ssm_d_inner), act,
                              "state"),

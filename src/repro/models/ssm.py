@@ -1,9 +1,17 @@
 """Mamba-2 (SSD) mixer — the attention-free assigned architecture.
 
 Decode shares the paper's persistent-state structure: per head h a state
-S^(h) in R^{d_state x d_head} updated as S <- g*S + B x^T with output
-y = S^T C — i.e. the GDN recurrence *without* the delta rule
-(`delta_rule=False` in the shared kernels; see DESIGN.md §Arch-applicability).
+updated as S <- g*S + B x^T with output y = S^T C — i.e. the GDN
+recurrence *without* the delta rule (`delta_rule=False` in the shared
+kernels; see DESIGN.md §Arch-applicability).
+
+The cached state is stored transposed, S^(h) in R^{d_head x d_state}
+(``SSMState``): the 128-wide d_state axis is minor, so on a TPU the
+state fills the 128 lanes of each tile.  Stored as (d_state, d_head)
+its minor axis would be d_head = 64 and every tile half padding.  Decode
+computes the step in the stored orientation (``ssd_decode_stored``);
+prefill and training run the shared chunkwise core, which works in
+(d_state, d_head), and swap the last two axes at the boundary.
 
 Projections are kept separate (w_z / w_x / w_B / w_C / w_dt) so tensor
 parallelism shards the per-head quantities (x, dt, heads) on the model axis
@@ -27,7 +35,14 @@ CONV_WIDTH = 4
 
 
 class SSMState(NamedTuple):
-    S: jax.Array          # (B, nheads, d_state, headdim) fp32
+    """Per-slot SSD cache.  ``S`` is (B, nheads, headdim, d_state): the
+    transpose of the shared core's (d_k, d_v) = (d_state, headdim), so
+    that d_state (128 at mamba2-1.3b's widths) is the minor axis.  Its
+    bytes are those of the other orientation; only the axis order
+    differs, so paging, gather/scatter, checkpoints and the disagg
+    handoff, which all take their shapes from the mixer's
+    ``cache_spec``, need nothing of their own."""
+    S: jax.Array          # (B, nheads, headdim, d_state) fp32
     conv_x: jax.Array     # (B, conv_width-1, d_inner)
     conv_B: jax.Array     # (B, conv_width-1, d_state)
     conv_C: jax.Array     # (B, conv_width-1, d_state)
@@ -78,6 +93,22 @@ def _out(p, y, z, xh, x_dtype):
     y = layers.rmsnorm_fwd(p["norm"], y.astype(x_dtype))
     y = y * _silu(z)
     return layers.dot(y, p["out_proj"])
+
+
+def _to_core(S):
+    """Stored (..., headdim, d_state) <-> core (..., d_state, headdim)."""
+    return jnp.swapaxes(S, -1, -2)
+
+
+def ssd_decode_stored(C, B, v, S, g):
+    """SSD decode step on the stored orientation.
+
+    C, B: (batch, d_state); v: (batch, nheads, headdim);
+    S: (batch, nheads, headdim, d_state); g: (batch, nheads).
+    S' = g S + v B^T ; o = S' C.  Returns o (batch, nheads, headdim), S'.
+    """
+    S_new = g[..., None, None] * S + v[..., :, None] * B[:, None, None, :]
+    return jnp.einsum("bhpn,bn->bhp", S_new, C), S_new
 
 
 def ssm_train(p, x, *, d_inner, headdim, d_state, chunk=64):
@@ -143,8 +174,9 @@ def ssm_prefill(p, x, state: SSMState, *, d_inner, headdim, d_state,
         from repro.kernels import ops
         O, S = ops.gdn_prefill(
             Ci[:, :, None, :], Bi[:, :, None, :], v, log_g,
-            ones, state.S, chunk=chunk, delta_rule=False,
+            ones, _to_core(state.S), chunk=chunk, delta_rule=False,
             valid_len=valid_len)
+        S = _to_core(S)
     else:
         Bk, vk, log_gk = Bi[:, :, None, :], v, log_g
         if valid_len is not None:
@@ -155,8 +187,9 @@ def ssm_prefill(p, x, state: SSMState, *, d_inner, headdim, d_state,
             Ci[:, :, None, :].astype(jnp.float32),
             Bk.astype(jnp.float32),
             vk.astype(jnp.float32), log_gk, ones,
-            state.S.astype(jnp.float32), chunk=chunk, delta_rule=False)
-        S = S.astype(state.S.dtype)
+            _to_core(state.S).astype(jnp.float32), chunk=chunk,
+            delta_rule=False)
+        S = _to_core(S).astype(state.S.dtype)
     out = _out(p, O.astype(x.dtype), z, xh, x.dtype)
     return out, SSMState(S=S, conv_x=cx.astype(state.conv_x.dtype),
                          conv_B=cB.astype(state.conv_B.dtype),
@@ -165,7 +198,8 @@ def ssm_prefill(p, x, state: SSMState, *, d_inner, headdim, d_state,
 
 def ssm_decode(p, x_t, state: SSMState, *, d_inner, headdim, d_state,
                use_pallas=False, head_block=8):
-    """One-token decode via the fused persistent-state kernel path."""
+    """One-token decode on the stored (headdim, d_state) state
+    (``ssd_decode_stored``), or through the fused kernel."""
     z = layers.dot(x_t, p["w_z"])
     xi, cx = layers.conv1d_decode(p["conv_x"], layers.dot(x_t, p["w_x"]),
                                   state.conv_x)
@@ -177,18 +211,16 @@ def ssm_decode(p, x_t, state: SSMState, *, d_inner, headdim, d_state,
     dt = layers.dot(x_t, p["w_dt"])
     xh, v, log_g = _ssd_terms(p, xi, Bi, Ci, dt, headdim)
     g = jnp.exp(log_g)
-    ones = jnp.ones_like(g)
     if use_pallas:
         from repro.kernels import ops
         o, S = ops.gdn_decode(Ci[:, None, :], Bi[:, None, :], v,
-                              state.S, g, ones, head_block=head_block,
-                              delta_rule=False)
+                              _to_core(state.S), g, jnp.ones_like(g),
+                              head_block=head_block, delta_rule=False)
+        S = _to_core(S)
     else:
-        o, S = gdn_core.gdn_decode(
-            Ci[:, None, :].astype(jnp.float32),
-            Bi[:, None, :].astype(jnp.float32),
-            v.astype(jnp.float32), state.S.astype(jnp.float32), g, ones,
-            fused=True, delta_rule=False)
+        o, S = ssd_decode_stored(
+            Ci.astype(jnp.float32), Bi.astype(jnp.float32),
+            v.astype(jnp.float32), state.S.astype(jnp.float32), g)
         S = S.astype(state.S.dtype)
     out = _out(p, o.astype(x_t.dtype), z, xh, x_t.dtype)
     return out, SSMState(S=S, conv_x=cx, conv_B=cB, conv_C=cC)

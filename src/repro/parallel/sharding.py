@@ -233,8 +233,9 @@ def cache_specs(cfg: ArchConfig, mesh: Mesh, caches_shape, batch: int):
         if ps.endswith("length"):
             return P(None, BD)
         if ps.endswith("/S"):
-            # linear state (R, B, Hv, dk, dv): heads on model (paper's
-            # head-parallelism); dk additionally on data at tiny batch
+            # linear state (R, B, Hv, dk, dv) — SSD's (R, B, H, hd, ds):
+            # heads on model (paper's head-parallelism); dim 3
+            # additionally on data at tiny batch
             if dp_ok:
                 return P(None, BD, "model", None, None)
             return P(None, None, "model", "data", None)

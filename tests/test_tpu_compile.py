@@ -7,22 +7,28 @@ loads, VMEM limits.  These tests compile each kernel with the TPU
 compiler for a described, unattached ``v5e:2x2`` chip — no device runs
 anything — at the widths of qwen3-next-gdn (GDN B=4, Hk=16, Hv=32,
 d=128; attention Hq=16, Hkv=2, d=128) and mamba2-1.3b (the
-``delta_rule=False`` path: Hk=1, Hv=64, d_k=128, d_v=64).
+``delta_rule=False`` path: Hk=1, Hv=64, d_k=128, d_v=64).  The last
+test compiles mamba2-1.3b's whole decode tick and reads the layout the
+compiler gave its SSD state.
 
 The topology is described inside a module fixture, never at import:
 only one process may load the TPU library, and pytest-xdist workers all
 import this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro import configs
 from repro.kernels.attn_decode import attn_decode_pallas
 from repro.kernels.gdn_decode import gdn_decode_pallas
 from repro.kernels.gdn_prefill import gdn_prefill_pallas
+from repro.models import lm
+from repro.serving import sampling
 
 BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
 
@@ -102,3 +108,52 @@ def test_attn_decode_compiles(one_chip, window):
         q, k, v, n, block_t=256, window=window), one_chip,
         ((B, Hq, d), BF16), ((B, Hkv, T, d), BF16), ((B, Hkv, T, d), BF16),
         ((B,), I32))
+
+
+def _f32_results(hlo: str, op: str, dims):
+    """(name, shape, layout) of every ``op`` instruction whose f32 result
+    holds ``dims`` in any order."""
+    pat = re.compile(r"%(\S+) = f32\[([\d,]+)\]\{([\d,]+)[^}]*\} "
+                     + re.escape(op) + r"\(")
+    out = []
+    for name, shape, layout in pat.findall(hlo):
+        shape = [int(d) for d in shape.split(",")]
+        if sorted(shape) == sorted(dims):
+            out.append((name, shape, [int(d) for d in layout.split(",")]))
+    return out
+
+
+def test_mamba2_decode_state_layout(one_chip):
+    """The decode tick (k=4, 32 slots, caches and sampler donated) keeps
+    the SSD state in its stored layout, with d_state = 128 on the lanes:
+    no whole-state transpose copy, every fusion writing the state has 128
+    minor, and no temporary of the state's size."""
+    cfg = configs.get_arch("mamba2-1.3b").replace(n_layers=2)
+    slots, max_len, k = 32, 2048, 4
+
+    def spec(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    params = spec(jax.eval_shape(lambda key: lm.init_lm(key, cfg),
+                                 jax.random.PRNGKey(0)))
+    caches = spec(jax.eval_shape(lambda: lm.init_caches(cfg, slots,
+                                                        max_len)))
+    sampler = spec(jax.eval_shape(lambda: sampling.init_state(slots)))
+    toks = jax.ShapeDtypeStruct((slots,), I32, sharding=one_chip)
+    compiled = jax.jit(
+        lambda p, t, c, s: lm.decode_steps(p, cfg, t, c, k, sampler=s,
+                                           sample_fn=sampling.sample),
+        donate_argnums=(2, 3)).lower(params, toks, caches,
+                                     sampler).compile()
+    hlo = compiled.as_text()
+    S = caches[0][0].S
+    assert S.dtype == F32 and S.shape[-1] == cfg.ssm_d_state == 128
+
+    assert _f32_results(hlo, "copy", S.shape) == []
+    writes = _f32_results(hlo, "fusion", S.shape)
+    assert writes, "no fusion writes the SSD state"
+    for name, shape, layout in writes:
+        assert shape[layout[0]] == 128, (name, shape, layout)
+    state_bytes = S.size * S.dtype.itemsize
+    assert compiled.memory_analysis().temp_size_in_bytes < state_bytes / 10
