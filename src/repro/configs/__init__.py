@@ -1,4 +1,5 @@
-"""Architecture registry: 10 assigned archs + the paper's own (qwen3-next GDN)."""
+"""Architecture registry: 10 assigned archs + the paper's own (qwen3-next
+GDN) + Qwen3-Next-80B-A3B's own block (one chip's share)."""
 from __future__ import annotations
 
 from repro.configs.base import (ArchConfig, ServingTopology, ShapeConfig,
@@ -14,16 +15,18 @@ from repro.configs.musicgen_medium import CONFIG as musicgen_medium
 from repro.configs.mamba2_1_3b import CONFIG as mamba2_1_3b
 from repro.configs.recurrentgemma_2b import CONFIG as recurrentgemma_2b
 from repro.configs.qwen3_next_gdn import CONFIG as qwen3_next_gdn
+from repro.configs.qwen3_next_80b_a3b import CONFIG as qwen3_next_80b_a3b
 
 ARCHS = {
     c.name: c for c in [
         llava_next_34b, minicpm_2b, minitron_8b, yi_9b, h2o_danube_1_8b,
         mixtral_8x7b, arctic_480b, musicgen_medium, mamba2_1_3b,
-        recurrentgemma_2b, qwen3_next_gdn,
+        recurrentgemma_2b, qwen3_next_gdn, qwen3_next_80b_a3b,
     ]
 }
 
-ASSIGNED = [n for n in ARCHS if n != "qwen3-next-gdn"]
+ASSIGNED = [n for n in ARCHS
+            if n not in ("qwen3-next-gdn", "qwen3-next-80b-a3b")]
 
 
 def get_arch(name: str) -> ArchConfig:

@@ -34,6 +34,8 @@ class ArchConfig:
     n_kv_heads_pad: int = 0
     window: Optional[int] = None      # sliding-window size for "swa" mixers
     rope_theta: float = 10000.0
+    rotary_dim: int = 0               # partial rotary: dims rotated per head
+                                      # (0 = the whole head)
     # ffn
     d_ff: int = 0
     moe_experts: int = 0
@@ -41,10 +43,15 @@ class ArchConfig:
     moe_group_size: int = 1024
     moe_capacity_factor: float = 1.25
     d_ff_dense: int = 0               # arctic's parallel dense-residual MLP
+    # experts this chip holds of the router's moe_experts, as one chip of
+    # an expert-parallel stage (the "qnext_moe" kind); 0 = all of them
+    moe_experts_held: int = 0
+    moe_shared_d_ff: int = 0          # shared expert's width ("qnext_moe")
     # gdn (paper layer)
     gdn_k_heads: int = 0
     gdn_v_heads: int = 0
     gdn_head_dim: int = 0
+    gdn_conv_width: int = 0           # short conv over q|k|v ("qnext_gdn")
     # ssm (mamba2)
     ssm_d_inner: int = 0
     ssm_headdim: int = 0
@@ -132,6 +139,14 @@ class ArchConfig:
             kw["d_ff_dense"] = 128
         if self.moe_experts:
             kw.update(moe_experts=4, moe_group_size=64)
+        if self.moe_experts_held:
+            # still a cut: half of the experts held, top-4 of 16
+            kw.update(moe_experts=16, moe_experts_held=8,
+                      moe_top_k=min(self.moe_top_k, 4))
+        if self.moe_shared_d_ff:
+            kw["moe_shared_d_ff"] = 64
+        if self.rotary_dim:
+            kw["rotary_dim"] = max(2, 16 * self.rotary_dim // self.head_dim)
         if self.gdn_v_heads:
             kw.update(gdn_k_heads=2, gdn_v_heads=4, gdn_head_dim=16)
         if self.ssm_d_inner:

@@ -11,6 +11,18 @@ Implements, in pure JAX:
   * chunkwise-parallel prefill:  gated UT/WY transform, log-space decay ratios
                                  (train/prefill path; O(T/C) sequential steps)
 
+Two orders of decay and delta (``decayed_read``):
+
+  * the paper's Algorithm 1 (default): the delta reads the undecayed state,
+        r = S^T k;  S <- g S + k (beta (v - r))^T
+  * the published Gated DeltaNet (arXiv:2412.06464; Qwen3-Next): decay
+    first, then the delta against the decayed state,
+        S <- g S;  r = S^T k;  S <- S + k (beta (v - r))^T
+    i.e. the same step with r scaled by g.  Its gate is
+    ``qnext_log_gate``: log g = -exp(A_log) softplus(a + dt_bias).
+
+Both share o = S_t^T q (scaled); a mixer kind picks the order.
+
 Shape conventions (single head):
   q, k          : (d_k,)
   v             : (d_v,)
@@ -46,6 +58,11 @@ def log_gate(alpha, A_log, dt_bias):
     return -jax.nn.sigmoid(alpha) * jnp.exp(A_log) * softplus(dt_bias)
 
 
+def qnext_log_gate(a, A_log, dt_bias):
+    """Qwen3-Next's gate: log g_t = -exp(A_log) * softplus(a_t + dt_bias)."""
+    return -jnp.exp(A_log) * softplus(a + dt_bias)
+
+
 def gates(alpha, b, A_log, dt_bias):
     """Paper Eqs. (5)-(6). Returns (g, beta), both in (0, 1)."""
     g = jnp.exp(log_gate(alpha, A_log, dt_bias))
@@ -57,10 +74,17 @@ def gates(alpha, b, A_log, dt_bias):
 # Single-head decode steps
 # ---------------------------------------------------------------------------
 
-def decode_step_naive(q, k, v, S, g, beta, *, scale=None):
-    """Alg. 1 — three logical passes over S (retrieval, update, output)."""
+def decode_step_naive(q, k, v, S, g, beta, *, scale=None,
+                      decayed_read=False):
+    """Alg. 1 — three logical passes over S (retrieval, update, output).
+    ``decayed_read``: decay S first and retrieve from the decayed state."""
     d_k = q.shape[-1]
     scale = (1.0 / math.sqrt(d_k)) if scale is None else scale
+    if decayed_read:
+        S = g * S                      # decay first
+        r = S.T @ k                    # pass 1 (read)
+        S_new = S + jnp.outer(k, beta * (v - r))
+        return scale * (S_new.T @ q), S_new
     r = S.T @ k                        # pass 1 (read)
     dv = beta * (v - r)                # delta correction
     S_new = g * S + jnp.outer(k, dv)   # pass 2 (read+write)
@@ -68,18 +92,23 @@ def decode_step_naive(q, k, v, S, g, beta, *, scale=None):
     return o, S_new
 
 
-def decode_step_fused(q, k, v, S, g, beta, *, scale=None):
+def decode_step_fused(q, k, v, S, g, beta, *, scale=None,
+                      decayed_read=False):
     """Alg. 2 — one read pass (computing r and o_hat together) + one write pass.
 
     The read pass stacks [k, q] into a single (2, d_k) @ (d_k, d_v) matmul:
     on TPU this is one MXU operation over a single traversal of S — the
-    direct analogue of the paper's shared-read-pass datapath.
+    direct analogue of the paper's shared-read-pass datapath.  With
+    ``decayed_read`` the retrieval is from g S, i.e. g (S^T k): the same
+    passes, one more scaling.
     """
     d_k = q.shape[-1]
     scale = (1.0 / math.sqrt(d_k)) if scale is None else scale
     kq = jnp.stack([k, q])             # (2, d_k)
     rr = kq @ S                        # (2, d_v): rr[0] = S^T k, rr[1] = S^T q
     r, sq = rr[0], rr[1]
+    if decayed_read:
+        r = g * r
     o_hat = g * sq
     dv = beta * (v - r)
     alpha = q @ k                      # phase 1: dot product
@@ -104,7 +133,7 @@ def ssd_decode_step(q, k, v, S, g, *, scale=None):
 # ---------------------------------------------------------------------------
 
 def prefill_sequential(q, k, v, log_g, beta, S0, *, scale=None,
-                       delta_rule=True):
+                       delta_rule=True, decayed_read=False):
     """Token-by-token scan. q,k: (T, d_k); v: (T, d_v); log_g, beta: (T,).
 
     Returns O: (T, d_v), S_final: (d_k, d_v).
@@ -118,7 +147,8 @@ def prefill_sequential(q, k, v, log_g, beta, S0, *, scale=None,
         g_t = jnp.exp(lg_t)
         if delta_rule:
             o, S_new = decode_step_fused(q_t, k_t, v_t, S, g_t, b_t,
-                                         scale=scale)
+                                         scale=scale,
+                                         decayed_read=decayed_read)
         else:
             o, S_new = ssd_decode_step(q_t, k_t, v_t, S, g_t, scale=scale)
         return S_new, o
@@ -139,18 +169,22 @@ def prefill_sequential(q, k, v, log_g, beta, S0, *, scale=None,
 #       M[t,s] = exp(L_t - L_s) * (q_t . k_s),               s <= t
 #   S_C = exp(L_C) S0 + (exp(L_C - L) ⊙ K)^T @ U
 #
+# With the decayed read, u_t = beta_t (v_t - g_t S_{t-1}^T k_t): L_{t-1}
+# becomes L_t in A and gamma_prev becomes gamma in the right-hand side.
+#
 # All decay ratios exp(L_a - L_b) have a >= b hence are <= 1: log-space is
 # numerically safe for arbitrarily strong gating.
 
-def _chunk_delta(q, k, v, log_g, beta, S0, scale):
+def _chunk_delta(q, k, v, log_g, beta, S0, scale, decayed_read=False):
     C, d_k = q.shape
     L = jnp.cumsum(log_g)                             # (C,)
-    L_prev = L - log_g                                # L_{t-1}
+    # the decay that the retrieval of token t sees
+    L_prev = L if decayed_read else L - log_g         # L_t or L_{t-1}
     gamma = jnp.exp(L)                                # (C,)
     gamma_prev = jnp.exp(L_prev)
 
     kk = k @ k.T                                      # (C, C)
-    decayA = jnp.exp(L_prev[:, None] - L[None, :])    # exp(L_{t-1} - L_s)
+    decayA = jnp.exp(L_prev[:, None] - L[None, :])    # exp(L_prev,t - L_s)
     A = beta[:, None] * decayA * kk
     A = jnp.tril(A, k=-1)                             # strictly lower
 
@@ -182,7 +216,7 @@ def _chunk_ssd(q, k, v, log_g, S0, scale):
 
 
 def prefill_chunkwise(q, k, v, log_g, beta, S0, *, chunk=64, scale=None,
-                      delta_rule=True):
+                      delta_rule=True, decayed_read=False):
     """Chunk-parallel prefill. T must be a multiple of `chunk` (pad upstream).
 
     q,k: (T, d_k); v: (T, d_v); log_g, beta: (T,); S0: (d_k, d_v).
@@ -203,7 +237,8 @@ def prefill_chunkwise(q, k, v, log_g, beta, S0, *, chunk=64, scale=None,
     def step(S, inp):
         qc, kc, vc, lgc, bc = inp
         if delta_rule:
-            O, S_new = _chunk_delta(qc, kc, vc, lgc, bc, S, scale)
+            O, S_new = _chunk_delta(qc, kc, vc, lgc, bc, S, scale,
+                                    decayed_read)
         else:
             O, S_new = _chunk_ssd(qc, kc, vc, lgc, S, scale)
         return S_new, O
@@ -223,14 +258,16 @@ def gva_expand(x, n_rep: int):
     return jnp.repeat(x, n_rep, axis=1)
 
 
-@partial(jax.jit, static_argnames=("fused", "scale", "delta_rule"))
+@partial(jax.jit, static_argnames=("fused", "scale", "delta_rule",
+                                   "decayed_read"))
 def gdn_decode(q, k, v, S, g, beta, *, fused=True, scale=None,
-               delta_rule=True):
+               delta_rule=True, decayed_read=False):
     """Batched GDN decode step.
 
     q, k : (B, Hk, d_k);  v: (B, Hv, d_v);  S: (B, Hv, d_k, d_v)
     g, beta: (B, Hv).  Hv must be a multiple of Hk (GVA ratio R = Hv // Hk).
-    delta_rule=False gives the mamba2/SSD update (beta ignored).
+    delta_rule=False gives the mamba2/SSD update (beta ignored);
+    decayed_read the published GDN order (see the module docstring).
     Returns o: (B, Hv, d_v), S_new: (B, Hv, d_k, d_v).
     """
     B, Hk, d_k = q.shape
@@ -239,15 +276,16 @@ def gdn_decode(q, k, v, S, g, beta, *, fused=True, scale=None,
     qe, ke = gva_expand(q, R), gva_expand(k, R)
     if delta_rule:
         fn = decode_step_fused if fused else decode_step_naive
-        fn = partial(fn, scale=scale)
+        fn = partial(fn, scale=scale, decayed_read=decayed_read)
         return jax.vmap(jax.vmap(fn))(qe, ke, v, S, g, beta)
     fn = partial(ssd_decode_step, scale=scale)
     return jax.vmap(jax.vmap(fn))(qe, ke, v, S, g)
 
 
-@partial(jax.jit, static_argnames=("chunk", "scale", "delta_rule"))
+@partial(jax.jit, static_argnames=("chunk", "scale", "delta_rule",
+                                   "decayed_read"))
 def gdn_prefill(q, k, v, log_g, beta, S0, *, chunk=64, scale=None,
-                delta_rule=True):
+                delta_rule=True, decayed_read=False):
     """Batched chunkwise prefill.
 
     q, k: (B, T, Hk, d_k); v: (B, T, Hv, d_v); log_g, beta: (B, T, Hv);
@@ -263,6 +301,6 @@ def gdn_prefill(q, k, v, log_g, beta, S0, *, chunk=64, scale=None,
     bh = beta.transpose(0, 2, 1)
 
     fn = partial(prefill_chunkwise, chunk=chunk, scale=scale,
-                 delta_rule=delta_rule)
+                 delta_rule=delta_rule, decayed_read=decayed_read)
     O, S = jax.vmap(jax.vmap(fn))(qe, ke, vh, lgh, bh, S0)
     return O.transpose(0, 2, 1, 3), S

@@ -12,6 +12,13 @@ Three entry points per layer:
 
 KV cache layout: (B, Hkv, Tmax, hd) + scalar lengths (B,).  For SWA archs the
 cache is a rolling buffer of ``window`` positions (O(1) memory at 500k ctx).
+
+Gated attention (Qwen3-Next, ``init_gated_attention``): the parameters
+add ``wg``, a second query-shaped projection whose sigmoid gates each
+head's output before ``wo``, and ``q_norm``/``k_norm``, an RMSNorm over
+each query and key head before the rotary embedding.  Every entry point
+applies them when the parameters hold them; ``rotary_dim`` rotates only
+the first dims of each head (partial rotary).
 """
 from __future__ import annotations
 
@@ -44,12 +51,43 @@ def init_attention(key, d_model, n_heads, n_kv_heads, head_dim,
     }
 
 
-def _qkv(p, x, positions, rope_theta):
+def init_gated_attention(key, d_model, n_heads, n_kv_heads, head_dim,
+                         dtype=jnp.float32):
+    """``init_attention`` (same keys) plus the output gate's projection
+    and the per-head q/k norms."""
+    p = init_attention(key, d_model, n_heads, n_kv_heads, head_dim, dtype)
+    p["wg"] = (jax.random.normal(jax.random.fold_in(key, 1),
+                                 (d_model, n_heads, head_dim))
+               * d_model ** -0.5).astype(dtype)
+    p["q_norm"] = layers.init_rmsnorm(head_dim)
+    p["k_norm"] = layers.init_rmsnorm(head_dim)
+    return p
+
+
+def _qk_norm(p, q, k):
+    if "q_norm" not in p:
+        return q, k
+    return (layers.rmsnorm_fwd(p["q_norm"], q),
+            layers.rmsnorm_fwd(p["k_norm"], k))
+
+
+def _gate(p, x, o):
+    """o * sigmoid(x wg) per head, where the parameters have a gate.
+    x: (..., d); o: (..., H, hd)."""
+    if "wg" not in p:
+        return o
+    g = jnp.einsum("...d,dhk->...hk", x, p["wg"],
+                   preferred_element_type=jnp.float32)
+    return (o.astype(jnp.float32) * jax.nn.sigmoid(g)).astype(o.dtype)
+
+
+def _qkv(p, x, positions, rope_theta, rotary_dim=None):
     q = jnp.einsum("btd,dhk->bthk", x, p["wq"]).astype(x.dtype)
     k = jnp.einsum("btd,dhk->bthk", x, p["wk"]).astype(x.dtype)
     v = jnp.einsum("btd,dhk->bthk", x, p["wv"]).astype(x.dtype)
-    q = layers.apply_rope(q, positions, rope_theta)
-    k = layers.apply_rope(k, positions, rope_theta)
+    q, k = _qk_norm(p, q, k)
+    q = layers.apply_rope(q, positions, rope_theta, rotary_dim)
+    k = layers.apply_rope(k, positions, rope_theta, rotary_dim)
     return q, k, v
 
 
@@ -116,10 +154,10 @@ def _apply_head_mask(o, head_mask):
 
 
 def attn_train(p, x, *, rope_theta=10000.0, window=None, block_kv=512,
-               use_flash_kernel=False, head_mask=None):
+               use_flash_kernel=False, head_mask=None, rotary_dim=None):
     B, T, _ = x.shape
     positions = jnp.broadcast_to(jnp.arange(T), (B, T))
-    q, k, v = _qkv(p, x, positions, rope_theta)
+    q, k, v = _qkv(p, x, positions, rope_theta, rotary_dim)
     if use_flash_kernel:
         # Pallas fused path: scores stay in VMEM, HBM traffic O(B·T·H·d)
         from repro.kernels import ops
@@ -128,12 +166,12 @@ def attn_train(p, x, *, rope_theta=10000.0, window=None, block_kv=512,
                             ops.interpret_mode())
     else:
         o = blockwise_attention(q, k, v, window=window, block_kv=block_kv)
-    o = _apply_head_mask(o, head_mask)
+    o = _apply_head_mask(_gate(p, x, o), head_mask)
     return jnp.einsum("bthk,hkd->btd", o, p["wo"]).astype(x.dtype)
 
 
 def attn_prefill(p, x, cache: KVCache, *, rope_theta=10000.0, window=None,
-                 block_kv=512, head_mask=None):
+                 block_kv=512, head_mask=None, rotary_dim=None):
     """Run full attention over the prompt and populate the cache.
 
     Assumes all sequences share length T (ragged prompts are left-padded by
@@ -141,7 +179,7 @@ def attn_prefill(p, x, cache: KVCache, *, rope_theta=10000.0, window=None,
     """
     B, T, _ = x.shape
     positions = jnp.broadcast_to(jnp.arange(T), (B, T))
-    q, k, v = _qkv(p, x, positions, rope_theta)
+    q, k, v = _qkv(p, x, positions, rope_theta, rotary_dim)
     o = blockwise_attention(q, k, v, window=window, block_kv=block_kv)
     size = cache.k.shape[2]
     kh = k.transpose(0, 2, 1, 3)          # (B, Hkv, T, hd)
@@ -159,13 +197,14 @@ def attn_prefill(p, x, cache: KVCache, *, rope_theta=10000.0, window=None,
     new_cache = KVCache(new_k.astype(cache.k.dtype),
                         new_v.astype(cache.v.dtype),
                         cache.length + T)
-    o = _apply_head_mask(o, head_mask)
+    o = _apply_head_mask(_gate(p, x, o), head_mask)
     out = jnp.einsum("bthk,hkd->btd", o, p["wo"]).astype(x.dtype)
     return out, new_cache
 
 
 def attn_prefill_chunk(p, x, cache: KVCache, *, rope_theta=10000.0,
-                       window=None, head_mask=None, valid_len=None):
+                       window=None, head_mask=None, valid_len=None,
+                       rotary_dim=None):
     """Process one prompt chunk *continuing from* the cache.
 
     Unlike ``attn_prefill`` (which assumes a fresh cache and positions
@@ -197,7 +236,7 @@ def attn_prefill_chunk(p, x, cache: KVCache, *, rope_theta=10000.0,
         raise ValueError(f"prefill chunk of {C} tokens exceeds the rolling "
                          f"KV buffer ({size}); lower the chunk size")
     pos = cache.length[:, None] + jnp.arange(C)[None, :]       # (B, C)
-    q, k, v = _qkv(p, x, pos, rope_theta)
+    q, k, v = _qkv(p, x, pos, rope_theta, rotary_dim)
     Hq, hd = q.shape[2], q.shape[3]
     Hkv = cache.k.shape[1]
     G = Hq // Hkv
@@ -256,7 +295,7 @@ def attn_prefill_chunk(p, x, cache: KVCache, *, rope_theta=10000.0,
     o = (w_cache[..., None] * o_cache + w_chunk[..., None] * o_chunk) \
         / jnp.maximum(l, 1e-30)[..., None]
     o = o.transpose(0, 3, 1, 2, 4).reshape(B, C, Hq, hd).astype(x.dtype)
-    o = _apply_head_mask(o, head_mask)
+    o = _apply_head_mask(_gate(p, x, o), head_mask)
     out = jnp.einsum("bthk,hkd->btd", o, p["wo"]).astype(x.dtype)
 
     # --- rolling insert of the chunk (distinct slots since C <= size) -
@@ -289,7 +328,7 @@ def _cache_insert(cache: KVCache, k_t, v_t):
 
 
 def attn_decode_xla(p, x_t, cache: KVCache, *, rope_theta=10000.0,
-                    window=None, head_mask=None):
+                    window=None, head_mask=None, rotary_dim=None):
     """One-token decode, pure-JAX (GSPMD-shardable einsum over the cache).
 
     x_t: (B, d_model). Returns (out (B, d_model), new_cache).
@@ -299,8 +338,11 @@ def attn_decode_xla(p, x_t, cache: KVCache, *, rope_theta=10000.0,
     q = jnp.einsum("bd,dhk->bhk", x_t, p["wq"]).astype(x_t.dtype)
     k = jnp.einsum("bd,dhk->bhk", x_t, p["wk"]).astype(x_t.dtype)
     v = jnp.einsum("bd,dhk->bhk", x_t, p["wv"]).astype(x_t.dtype)
-    q = layers.apply_rope(q[:, None], pos[:, None], rope_theta)[:, 0]
-    k = layers.apply_rope(k[:, None], pos[:, None], rope_theta)[:, 0]
+    q, k = _qk_norm(p, q, k)
+    q = layers.apply_rope(q[:, None], pos[:, None], rope_theta,
+                          rotary_dim)[:, 0]
+    k = layers.apply_rope(k[:, None], pos[:, None], rope_theta,
+                          rotary_dim)[:, 0]
     cache = _cache_insert(cache, k, v)
 
     size = cache.k.shape[2]
@@ -327,7 +369,7 @@ def attn_decode_xla(p, x_t, cache: KVCache, *, rope_theta=10000.0,
     o = jnp.einsum("bhgt,bhtd->bhgd", p_att.astype(cache.v.dtype), cache.v,
                    preferred_element_type=jnp.float32)
     o = o.reshape(B, Hq, hd).astype(x_t.dtype)
-    o = _apply_head_mask(o, head_mask)
+    o = _apply_head_mask(_gate(p, x_t, o), head_mask)
     out = jnp.einsum("bhk,hkd->bd", o, p["wo"]).astype(x_t.dtype)
     return out, cache
 

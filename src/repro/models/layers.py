@@ -37,8 +37,15 @@ def rope_freqs(head_dim, theta=10000.0):
                             / head_dim))
 
 
-def apply_rope(x, positions, theta=10000.0):
-    """x: (..., T, H, hd) or (..., H, hd) with positions broadcastable."""
+def apply_rope(x, positions, theta=10000.0, rotary_dim=None):
+    """x: (..., T, H, hd) or (..., H, hd) with positions broadcastable.
+
+    ``rotary_dim`` (partial rotary): only the first ``rotary_dim`` dims
+    of each head rotate, with the frequencies of a ``rotary_dim``-wide
+    head; the rest pass through."""
+    if rotary_dim is not None and rotary_dim < x.shape[-1]:
+        rot = apply_rope(x[..., :rotary_dim], positions, theta)
+        return jnp.concatenate([rot, x[..., rotary_dim:]], -1)
     hd = x.shape[-1]
     freqs = rope_freqs(hd, theta)                     # (hd/2,)
     ang = positions[..., None].astype(jnp.float32) * freqs   # (..., T, hd/2)
@@ -90,17 +97,25 @@ def logits_fwd(p, x, table=None):
 # ------------------------------------------------------------------ causal conv1d
 # (mamba2 / short-conv mixers; decode keeps a (width-1)-token cache)
 
-def init_conv1d(key, channels, width, dtype=jnp.float32):
+def init_conv1d(key, channels, width, dtype=jnp.float32, bias=True):
     w = jax.random.normal(key, (width, channels)) * (width ** -0.5)
-    return {"w": w.astype(dtype), "b": jnp.zeros((channels,), dtype)}
+    p = {"w": w.astype(dtype)}
+    if bias:
+        p["b"] = jnp.zeros((channels,), dtype)
+    return p
+
+
+def silu(x):
+    """SiLU in float32, returned in ``x``'s dtype."""
+    return jax.nn.silu(x.astype(jnp.float32)).astype(x.dtype)
 
 
 def conv1d_fwd(p, x):
-    """Causal depthwise conv over (B, T, C)."""
+    """Causal depthwise conv over (B, T, C) (bias optional)."""
     width = p["w"].shape[0]
     pad = jnp.pad(x, ((0, 0), (width - 1, 0), (0, 0)))
     out = sum(pad[:, i:i + x.shape[1], :] * p["w"][i] for i in range(width))
-    return out + p["b"]
+    return out + p["b"] if "b" in p else out
 
 
 def conv1d_decode(p, x_t, cache):
@@ -108,8 +123,36 @@ def conv1d_decode(p, x_t, cache):
     width = p["w"].shape[0]
     full = jnp.concatenate([cache, x_t[:, None, :]], axis=1)  # (B, width, C)
     y = jnp.einsum("bwc,wc->bc", full.astype(jnp.float32),
-                   p["w"].astype(jnp.float32)).astype(x_t.dtype) + p["b"]
-    return y, full[:, 1:, :]
+                   p["w"].astype(jnp.float32)).astype(x_t.dtype)
+    return (y + p["b"] if "b" in p else y), full[:, 1:, :]
+
+
+def conv1d_prefill(p, u, cache, valid_len=None):
+    """Causal conv seeded with the carry ``cache`` (B, width-1, C), then
+    SiLU; returns (activated output (B, T, C), new carry).
+
+    With ``valid_len`` set, the returned carry is the last ``w - 1``
+    *valid* inputs (rows ``[valid_len, valid_len + w - 1)`` of
+    cache‖u) — the carry serial decode would hold after the valid
+    prefix, not the padded garbage at the block's end.  ``valid_len`` may
+    be a per-row (B,) vector (the batched staging path): each row's carry
+    is gathered at its own boundary; a scalar keeps the
+    ``dynamic_slice`` path bitwise-unchanged.
+    """
+    T = u.shape[1]
+    w = p["w"].shape[0]
+    full = jnp.concatenate([cache.astype(u.dtype), u], axis=1)
+    out = conv1d_fwd(p, full)[:, -T:, :]
+    if valid_len is None:
+        tail = full[:, -(w - 1):, :]
+    else:
+        vl = jnp.asarray(valid_len, jnp.int32)
+        if vl.ndim == 0:
+            tail = jax.lax.dynamic_slice_in_dim(full, vl, w - 1, axis=1)
+        else:
+            idx = vl[:, None] + jnp.arange(w - 1)[None, :]   # (B, w-1)
+            tail = jnp.take_along_axis(full, idx[:, :, None], axis=1)
+    return silu(out), tail
 
 
 # ------------------------------------------------------------------ loss

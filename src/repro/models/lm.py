@@ -30,12 +30,16 @@ Entry points:
   decode_steps(params, cfg, tokens, caches, k, sampler, sample_fn)
                                             -> k fused decode+sample steps
                                                (one host sync per k tokens)
+  decode_counter_names(cfg)                 -> the counters
+                                               ``decode_steps(count=True)``
+                                               reports per step
 
 The cached paths name their layers for the profiler: each mixer call runs
 under ``jax.named_scope("mixer_<kind>")``, each FFN under ``"ffn"``, and
 the final norm, head and sampler under ``"head_sample"``.  A scope only
 labels the operations (their HLO ``op_name``); the computation is the
-same.
+same.  A mixer kind may nest scopes of its own (``qnext_moe``:
+``moe_router``, ``moe_experts``, ``moe_shared``).
 
 VLM / audio archs: the modality frontend is a stub per the assignment —
 ``embeds`` (precomputed patch/frame embeddings, (B, T, d_model)) are fed
@@ -261,13 +265,26 @@ def checkpoint_specs(cfg: ArchConfig, batch: int, max_len: int) -> CacheSpec:
 
 # ---------------------------------------------------------------- prefill / decode
 
+def decode_counter_names(cfg: ArchConfig) -> Tuple[str, ...]:
+    """The decode counters of ``cfg``'s mixer kinds (``decode_counters``),
+    in a fixed order; empty for a model whose kinds count nothing."""
+    return tuple(sorted({n for k in cfg.pattern
+                         for n in get_mixer(k).decode_counters}))
+
+
 def _run_cached(params, cfg: ArchConfig, x, caches, mode: str,
-                dp_axes=None, valid_len=None):
+                dp_axes=None, valid_len=None, live=None):
+    """The layers over ``caches``.  With ``live`` (decode only) the kinds
+    that have decode counters report them over the live rows, summed over
+    layers, and a third value {name: int32} is returned."""
     groups = build_groups(cfg)
     new_caches = []
+    names = decode_counter_names(cfg) if live is not None else ()
+    counts = {n: jnp.zeros((), jnp.int32) for n in names}
     for (kinds, reps), gp, gc in zip(groups, params["groups"], caches):
 
-        def block(x, sl, kinds=kinds):
+        def block(carry, sl, kinds=kinds):
+            x, counts = carry
             lp_slice, c_slice = sl
             new_c = []
             for i, kind in enumerate(kinds):
@@ -282,6 +299,11 @@ def _run_cached(params, cfg: ArchConfig, x, caches, mode: str,
                         mix, nc = mixer.prefill_chunk(lp["mixer"], cfg, h,
                                                       c_slice[i],
                                                       valid_len=valid_len)
+                    elif live is not None and mixer.decode_counters:
+                        mix, nc, c = mixer.decode_counted(
+                            lp["mixer"], cfg, h, c_slice[i], live)
+                        counts = {n: counts[n] + c.get(n, 0)
+                                  for n in counts}
                     else:
                         mix, nc = mixer.decode(lp["mixer"], cfg, h,
                                                c_slice[i])
@@ -290,11 +312,11 @@ def _run_cached(params, cfg: ArchConfig, x, caches, mode: str,
                     x, _ = _ffn_fwd(cfg, lp, x, decode=(mode == "decode"))
                 x = _constrain(x, dp_axes)
                 new_c.append(nc)
-            return x, new_c
+            return (x, counts), new_c
 
-        x, ncs = jax.lax.scan(block, x, (gp, gc))
+        (x, counts), ncs = jax.lax.scan(block, (x, counts), (gp, gc))
         new_caches.append(ncs)
-    return x, new_caches
+    return (x, new_caches) if live is None else (x, new_caches, counts)
 
 
 def prefill(params, cfg: ArchConfig, caches, tokens=None, embeds=None,
@@ -406,15 +428,20 @@ def prefill_sample(params, cfg: ArchConfig, caches, sampler, sample_fn,
     return tok.astype(jnp.int32), sampler, caches
 
 
-def decode_step(params, cfg: ArchConfig, tokens_t, caches, dp_axes=None):
-    """One decode step. tokens_t: (B,) int32. Returns (logits (B, V), caches)."""
+def decode_step(params, cfg: ArchConfig, tokens_t, caches, dp_axes=None,
+                live=None):
+    """One decode step. tokens_t: (B,) int32. Returns (logits (B, V), caches);
+    with ``live`` ((B,) bool) also the decode counters over the live rows
+    ({name: int32}, see ``decode_counter_names``)."""
     x = layers.embed_fwd(params["embed"], tokens_t)
     x = _constrain(x.astype(jnp.dtype(cfg.act_dtype)), dp_axes)
-    x, caches = _run_cached(params, cfg, x, caches, "decode",
-                            dp_axes=dp_axes)
+    out = _run_cached(params, cfg, x, caches, "decode", dp_axes=dp_axes,
+                      live=live)
+    x, caches = out[:2]
     with jax.named_scope("head_sample"):
         x = layers.rmsnorm_fwd(params["final_norm"], x, cfg.norm_eps)
-        return _logits(params, cfg, x), caches
+        logits = _logits(params, cfg, x)
+    return (logits, caches) if live is None else (logits, caches, out[2])
 
 
 def _greedy_sample(sampler, logits):
@@ -423,7 +450,7 @@ def _greedy_sample(sampler, logits):
 
 
 def decode_steps(params, cfg: ArchConfig, tokens, caches, k: int,
-                 sampler=None, sample_fn=None, dp_axes=None):
+                 sampler=None, sample_fn=None, dp_axes=None, count=False):
     """``k`` fused decode+sample steps in one ``lax.scan``.
 
     This is the device-resident decode hot loop: the recurrent state,
@@ -446,6 +473,9 @@ def decode_steps(params, cfg: ArchConfig, tokens, caches, k: int,
     Returns ``(toks (k, B) int32, valid (k, B) bool, tokens (B,),
     caches, sampler)`` — ``toks[j]`` is the token batch from step j,
     ``valid[j]`` whether each slot was still live going into step j.
+    With ``count`` a sixth value follows: {name: (k,) int32}, each step's
+    decode counters over the slots live going into it
+    (``decode_counter_names``).
     """
     if sample_fn is None:
         sample_fn = _greedy_sample
@@ -455,15 +485,20 @@ def decode_steps(params, cfg: ArchConfig, tokens, caches, k: int,
     def step(carry, _):
         toks, cs, st = carry
         live = ~st["done"]
-        logits, cs = decode_step(params, cfg, toks, cs, dp_axes=dp_axes)
+        if count:
+            logits, cs, counts = decode_step(params, cfg, toks, cs,
+                                             dp_axes=dp_axes, live=live)
+        else:
+            logits, cs = decode_step(params, cfg, toks, cs, dp_axes=dp_axes)
         with jax.named_scope("head_sample"):
             nxt, st = sample_fn(st, logits)
             nxt = jnp.where(live, nxt, toks)
-        return (nxt, cs, st), (nxt, live)
+        out = (nxt, live, counts) if count else (nxt, live)
+        return (nxt, cs, st), out
 
-    (tokens, caches, sampler), (toks, valid) = jax.lax.scan(
+    (tokens, caches, sampler), out = jax.lax.scan(
         step, (tokens, caches, sampler), None, length=k)
-    return toks, valid, tokens, caches, sampler
+    return (*out[:2], tokens, caches, sampler, *out[2:])
 
 
 def verify_steps(params, cfg: ArchConfig, draft_params, draft_cfg,

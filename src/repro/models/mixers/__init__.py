@@ -38,6 +38,9 @@ from repro.models.mixers import gdn as _gdn              # noqa: E402,F401
 from repro.models.mixers import gdn_naive as _gdn_naive  # noqa: E402,F401
 from repro.models.mixers import ssm as _ssm              # noqa: E402,F401
 from repro.models.mixers import rglru as _rglru          # noqa: E402,F401
+from repro.models.mixers import qnext_gdn as _qnext_gdn  # noqa: E402,F401
+from repro.models.mixers import qnext_attn as _qnext_attn  # noqa: E402,F401
+from repro.models.mixers import qnext_moe as _qnext_moe  # noqa: E402,F401
 
 __all__ = ["ArraySpec", "CacheSpec", "SequenceMixer", "MIXERS",
            "register", "get_mixer"]

@@ -29,6 +29,10 @@ class Attention(SequenceMixer):
         return None
 
     @classmethod
+    def _rotary_dim(cls, cfg):
+        return None
+
+    @classmethod
     def init_params(cls, key, cfg, dtype):
         return attention.init_attention(key, cfg.d_model, cfg.hq_eff,
                                         cfg.hkv_eff, cfg.head_dim, dtype)
@@ -38,14 +42,16 @@ class Attention(SequenceMixer):
         return attention.attn_train(params, x, rope_theta=cfg.rope_theta,
                                     window=cls._window(cfg),
                                     use_flash_kernel=cfg.use_flash_kernel,
-                                    head_mask=_head_mask(cfg))
+                                    head_mask=_head_mask(cfg),
+                                    rotary_dim=cls._rotary_dim(cfg))
 
     @classmethod
     def prefill(cls, params, cfg, x, cache):
         return attention.attn_prefill(params, x, cache,
                                       rope_theta=cfg.rope_theta,
                                       window=cls._window(cfg),
-                                      head_mask=_head_mask(cfg))
+                                      head_mask=_head_mask(cfg),
+                                      rotary_dim=cls._rotary_dim(cfg))
 
     @classmethod
     def prefill_chunk(cls, params, cfg, x, cache, valid_len=None):
@@ -57,14 +63,16 @@ class Attention(SequenceMixer):
                                             rope_theta=cfg.rope_theta,
                                             window=cls._window(cfg),
                                             head_mask=_head_mask(cfg),
-                                            valid_len=valid_len)
+                                            valid_len=valid_len,
+                                            rotary_dim=cls._rotary_dim(cfg))
 
     @classmethod
     def decode(cls, params, cfg, x_t, cache):
         return attention.attn_decode_xla(params, x_t, cache,
                                          rope_theta=cfg.rope_theta,
                                          window=cls._window(cfg),
-                                         head_mask=_head_mask(cfg))
+                                         head_mask=_head_mask(cfg),
+                                         rotary_dim=cls._rotary_dim(cfg))
 
     @classmethod
     def cache_spec(cls, cfg, batch, max_len):

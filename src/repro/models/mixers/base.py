@@ -19,6 +19,7 @@ sharding planner and the intensity model:
   quadratic     O(T) decode state (unwindowed full attention)
   state_passes  HBM round-trips over the persistent state per decoded token
                 on a naive (non-persistent) backend: reads + writes
+  decode_counters  counters its ``decode_counted`` reports per step
 
 ``CacheSpec`` mirrors the runtime cache pytree with ``ArraySpec`` leaves, so
 slot buffers, byte budgets and roofline terms are all derived from one
@@ -128,6 +129,10 @@ class SequenceMixer:
     # executor falls back to per-prompt dispatch otherwise.  Implies
     # supports_ragged_prefill.
     supports_batched_ragged_prefill: bool = False
+    # names of the counters ``decode_counted`` reports per decode step
+    # (summed over the kind's layers by ``lm.decode_steps(count=True)``);
+    # a kind without counters never computes any
+    decode_counters: Tuple[str, ...] = ()
 
     @classmethod
     def init_params(cls, key, cfg, dtype):
@@ -170,6 +175,13 @@ class SequenceMixer:
 
     @classmethod
     def decode(cls, params, cfg, x_t, cache):
+        raise NotImplementedError(cls.kind)
+
+    @classmethod
+    def decode_counted(cls, params, cfg, x_t, cache, live):
+        """``decode`` plus this kind's ``decode_counters`` over the rows
+        where ``live`` ((B,) bool) is set: returns (out, cache,
+        {name: int32 scalar})."""
         raise NotImplementedError(cls.kind)
 
     @classmethod

@@ -13,11 +13,26 @@ Decode uses a dense all-expert einsum: at serving batch sizes every expert
 is hit with near-certainty, so weight *traffic* (the roofline term that
 dominates decode) is identical to a gather-based dispatch, with no dynamic
 shapes.  Load-balancing auxiliary loss included for training.
+
+Held experts (``held_*``, the ``qnext_moe`` mixer kind): an expert layer
+that is told which experts it holds — ``n_held`` consecutive experts
+from ``first`` of the router's ``n_experts`` — as one chip of an
+expert-parallel deployment holds them.  The softmax router spans all
+experts and picks ``top_k``, renormalized over the k (Qwen3-Next's
+``norm_topk_prob``); the layer computes only its held experts' part of
+the result, with no capacity and no dropped token: every held expert's
+weights multiply every token, scaled by its combine weight (0 where the
+token did not pick it).  A shared SwiGLU expert, gated by
+sigmoid(x w_gate), is added on every chip alike.  Expert e's weights are
+drawn from its own key, so any share draws the experts a whole layer
+would hold at those indices.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+
+from repro.models import layers
 
 
 def init_moe(key, d_model, d_ff, n_experts, dtype=jnp.float32):
@@ -107,3 +122,76 @@ def moe_decode(p, x_t, *, top_k=2):
     ye = jnp.einsum("bef,efd->bed", hh, p["wo"],
                     preferred_element_type=jnp.float32).astype(x_t.dtype)
     return jnp.einsum("bed,be->bd", ye, w)
+
+
+# ---------------------------------------------------------------- held experts
+
+def init_held_moe(key, d_model, d_ff, n_experts, n_held, shared_d_ff,
+                  dtype=jnp.float32, first=0):
+    ks = jax.random.split(key, 6)
+    s, sf = d_model ** -0.5, d_ff ** -0.5
+
+    def experts(k, shape, scale):
+        keys = jax.vmap(lambda e: jax.random.fold_in(k, e))(
+            first + jnp.arange(n_held))
+        return (jax.vmap(lambda ke: jax.random.normal(ke, shape))(keys)
+                * scale).astype(dtype)
+
+    return {
+        "router": (jax.random.normal(ks[0], (d_model, n_experts)) * s
+                   ).astype(dtype),
+        "wi_gate": experts(ks[1], (d_model, d_ff), s),
+        "wi_up": experts(ks[2], (d_model, d_ff), s),
+        "wo": experts(ks[3], (d_ff, d_model), sf),
+        "shared": layers.init_mlp(ks[4], d_model, shared_d_ff, dtype),
+        "shared_gate": (jax.random.normal(ks[5], (d_model, 1)) * s
+                        ).astype(dtype),
+    }
+
+
+def held_routing(p, x, *, top_k, first=0):
+    """Route over every expert; return the held experts' combine weights
+    (..., n_held) float32 (0 where a token did not pick one) and
+    per-token picks of each held expert (..., n_held) int32."""
+    n = p["wi_gate"].shape[0]
+    logits = jnp.einsum("...d,de->...e", x, p["router"],
+                        preferred_element_type=jnp.float32)
+    vals, idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+    vals = vals / jnp.sum(vals, -1, keepdims=True)
+    local = idx - first
+    local = jnp.where((local >= 0) & (local < n), local, n)   # n: absent
+    picks = jax.nn.one_hot(local, n + 1, dtype=jnp.int32)[..., :n]
+    w = jnp.einsum("...ke,...k->...e", picks.astype(jnp.float32), vals)
+    return w, jnp.sum(picks, -2)
+
+
+def held_experts(p, x, w):
+    """sum_e w_e SwiGLU_e(x) over the held experts, dropless: one matmul
+    per projection over all held experts, the combine folded into the
+    down projection."""
+    h = jnp.einsum("...d,edf->...ef", x, p["wi_gate"],
+                   preferred_element_type=jnp.float32)
+    u = jnp.einsum("...d,edf->...ef", x, p["wi_up"],
+                   preferred_element_type=jnp.float32)
+    a = (jax.nn.silu(h) * u * w[..., None]).astype(x.dtype)
+    return jnp.einsum("...ef,efd->...d", a, p["wo"],
+                      preferred_element_type=jnp.float32).astype(x.dtype)
+
+
+def shared_expert(p, x):
+    g = jnp.einsum("...d,do->...o", x, p["shared_gate"],
+                   preferred_element_type=jnp.float32)
+    y = layers.mlp_fwd(p["shared"], x)
+    return (y.astype(jnp.float32) * jax.nn.sigmoid(g)).astype(x.dtype)
+
+
+def held_moe(p, x, *, top_k, first=0):
+    """The held experts' part plus the shared expert; x: (..., d).
+    Returns (y, per-token picks of each held expert (..., n_held))."""
+    with jax.named_scope("moe_router"):
+        w, picks = held_routing(p, x, top_k=top_k, first=first)
+    with jax.named_scope("moe_experts"):
+        y = held_experts(p, x, w)
+    with jax.named_scope("moe_shared"):
+        y = y + shared_expert(p, x)
+    return y, picks

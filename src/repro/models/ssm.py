@@ -72,8 +72,7 @@ def init_ssm(key, d_model, d_inner, headdim, d_state,
     }
 
 
-def _silu(x):
-    return jax.nn.silu(x.astype(jnp.float32)).astype(x.dtype)
+_silu = layers.silu
 
 
 def _ssd_terms(p, x_in, B_in, C_in, dt, headdim):
@@ -130,42 +129,15 @@ def ssm_train(p, x, *, d_inner, headdim, d_state, chunk=64):
     return _out(p, O.astype(x.dtype), z, xh, x.dtype)
 
 
-def _conv_prefill(conv_p, u, cache, valid_len=None):
-    """Seeded causal conv; returns (activated output, new cache tail).
-
-    With ``valid_len`` set, the returned carry is the last ``w - 1``
-    *valid* inputs (rows ``[valid_len, valid_len + w - 1)`` of
-    cache‖u) — the carry serial decode would hold after the valid
-    prefix, not the padded garbage at the block's end.  ``valid_len`` may
-    be a per-row (B,) vector (the batched staging path): each row's carry
-    is gathered at its own boundary; a scalar keeps the
-    ``dynamic_slice`` path bitwise-unchanged.
-    """
-    T = u.shape[1]
-    w = conv_p["w"].shape[0]
-    full = jnp.concatenate([cache.astype(u.dtype), u], axis=1)
-    out = layers.conv1d_fwd(conv_p, full)[:, -T:, :]
-    if valid_len is None:
-        tail = full[:, -(w - 1):, :]
-    else:
-        vl = jnp.asarray(valid_len, jnp.int32)
-        if vl.ndim == 0:
-            tail = jax.lax.dynamic_slice_in_dim(full, vl, w - 1, axis=1)
-        else:
-            idx = vl[:, None] + jnp.arange(w - 1)[None, :]   # (B, w-1)
-            tail = jnp.take_along_axis(full, idx[:, :, None], axis=1)
-    return _silu(out), tail
-
-
 def ssm_prefill(p, x, state: SSMState, *, d_inner, headdim, d_state,
                 chunk=64, use_pallas=False, valid_len=None):
     B, T, _ = x.shape
     z = layers.dot(x, p["w_z"])
-    xi, cx = _conv_prefill(p["conv_x"], layers.dot(x, p["w_x"]),
+    xi, cx = layers.conv1d_prefill(p["conv_x"], layers.dot(x, p["w_x"]),
                            state.conv_x, valid_len)
-    Bi, cB = _conv_prefill(p["conv_B"], layers.dot(x, p["w_B"]),
+    Bi, cB = layers.conv1d_prefill(p["conv_B"], layers.dot(x, p["w_B"]),
                            state.conv_B, valid_len)
-    Ci, cC = _conv_prefill(p["conv_C"], layers.dot(x, p["w_C"]),
+    Ci, cC = layers.conv1d_prefill(p["conv_C"], layers.dot(x, p["w_C"]),
                            state.conv_C, valid_len)
     dt = layers.dot(x, p["w_dt"])
     xh, v, log_g = _ssd_terms(p, xi, Bi, Ci, dt, headdim)

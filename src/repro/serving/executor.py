@@ -536,6 +536,10 @@ class DeviceExecutor:
         # ``serve:*.sync`` spans)
         self.tick = 0
         self.sync_s = 0.0
+        # the decode counters of the model's mixer kinds, and the last
+        # decode tick's values per step ({} for a model that counts none)
+        self._counters = lm.decode_counter_names(cfg)
+        self.decode_counts: Dict[str, List[int]] = {}
         # donate only the slot buffers: the staging pytree's (repeats, 1,
         # ...) leaves have no same-shape output to alias (XLA would warn)
         self._scatter_p = self._jit(
@@ -1351,22 +1355,35 @@ class DeviceExecutor:
     # ------------------------------------------------------------- ticks
     def decode(self, k: int):
         """One fused k-step decode+sample tick over all slots; the single
-        host sync reads the (k, slots) token/validity arrays."""
+        host sync reads the (k, slots) token/validity arrays, and with
+        them, for a model whose mixer kinds count (``lm.decode_counter_
+        names``), each step's counters into ``decode_counts``."""
+        names = self._counters
         prog = self._decode_p.get(k)
         if prog is None:
+            out_sh = None
+            if self.mesh is not None:
+                out_sh = (self._sh_toks2d, self._sh_toks2d, self._sh_tokens,
+                          self._sh_caches, self._sh_sampler)
+                if names:
+                    out_sh += ({n: self._sh_rep for n in names},)
             prog = self._jit(
                 "decode",
                 lambda p, t, c, s, k=k: lm.decode_steps(
                     p, self.cfg, t, c, k,
-                    sampler=s, sample_fn=sampling.sample),
+                    sampler=s, sample_fn=sampling.sample,
+                    count=bool(names)),
                 donate=(2, 3),
                 in_sh=(self._sh_params, self._sh_tokens, self._sh_caches,
                        self._sh_sampler),
-                out_sh=((self._sh_toks2d, self._sh_toks2d, self._sh_tokens,
-                         self._sh_caches, self._sh_sampler)
-                        if self.mesh is not None else None))
+                out_sh=out_sh)
             self._decode_p[k] = prog
         with spans.span("decode.dispatch", tick=self.tick):
-            toks, valid, self.tokens, self.caches, self.sampler = prog(
-                self.params, self.tokens, self.caches, self.sampler)
-        return self._sync("decode", toks, valid)
+            out = prog(self.params, self.tokens, self.caches, self.sampler)
+            toks, valid, self.tokens, self.caches, self.sampler = out[:5]
+        if not names:
+            return self._sync("decode", toks, valid)
+        toks, valid, *counts = self._sync(
+            "decode", toks, valid, *(out[5][n] for n in names))
+        self.decode_counts = {n: c.tolist() for n, c in zip(names, counts)}
+        return toks, valid

@@ -420,7 +420,9 @@ class Scheduler:
         # step, the live slots and their summed contexts; per prefill
         # dispatch its program and rows (rid, start position, valid
         # tokens); the host time of ``step`` less its waits for the
-        # device (``serve:*.sync``); programs compiled or loaded in step
+        # device (``serve:*.sync``); programs compiled or loaded in step.
+        # A model whose mixer kinds count (``qnext_moe``) adds each
+        # step's counters to its tick's entry, by name
         self._tick = 0
         self.tick_log: Deque[dict] = deque(maxlen=LOG_LEN)
         self.prefill_log: Deque[dict] = deque(maxlen=LOG_LEN)
@@ -1620,7 +1622,7 @@ class Scheduler:
                         self.free.append(slot)
                         break
         self.tick_log.append({"tick": self._tick, "k": k, "live": live,
-                              "ctx": ctx})
+                              "ctx": ctx, **self.executor.decode_counts})
 
     def run_until_done(self, max_ticks: int = 10_000, *,
                        strict: bool = True) -> List[Request]:

@@ -9,14 +9,19 @@ anything — at the widths of qwen3-next-gdn (GDN B=4, Hk=16, Hv=32,
 d=128; attention Hq=16, Hkv=2, d=128) and mamba2-1.3b (the
 ``delta_rule=False`` path: Hk=1, Hv=64, d_k=128, d_v=64).  The last
 test compiles mamba2-1.3b's whole decode tick and reads the layout the
-compiler gave its SSD state.
+compiler gave its SSD state, and the decode ticks of qwen3-next-gdn and
+mamba2-1.3b are compared with the text the compiler gave them before the
+``qnext_*`` mixer kinds shared their code.
 
 The topology is described inside a module fixture, never at import:
 only one process may load the TPU library, and pytest-xdist workers all
 import this file.
 """
+import hashlib
+import json
 import os
 import re
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -157,3 +162,52 @@ def test_mamba2_decode_state_layout(one_chip):
         assert shape[layout[0]] == 128, (name, shape, layout)
     state_bytes = S.size * S.dtype.itemsize
     assert compiled.memory_analysis().temp_size_in_bytes < state_bytes / 10
+
+
+# sha256 of the normalized decode-tick text (``_decode_text``), compiled
+# at the parent of the change that added the ``qwen3-next-80b-a3b`` kinds
+DECODE_TEXT = Path(__file__).parent / "golden" / "decode_text.json"
+
+
+def _normalized(hlo: str) -> str:
+    """The compiled text less what names and locates it: the tables of
+    file names and stack frames, each instruction's ``metadata``, and the
+    numbers the compiler appends to instruction names."""
+    lines = hlo.splitlines()
+    start = next(i for i, line in enumerate(lines)
+                 if line.startswith(("%", "ENTRY")))
+    text = "\n".join(lines[:1] + lines[start:])
+    text = re.sub(r",?\s*metadata=\{[^{}]*\}", "", text)
+    return re.sub(r"(%[A-Za-z_][\w\-]*?)\.\d+\b", r"\1", text)
+
+
+def _decode_text(sharding, arch, layers, slots, max_len, k=4):
+    cfg = configs.get_arch(arch).replace(n_layers=layers)
+
+    def spec(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=sharding), tree)
+
+    params = spec(jax.eval_shape(lambda key: lm.init_lm(key, cfg),
+                                 jax.random.PRNGKey(0)))
+    caches = spec(jax.eval_shape(lambda: lm.init_caches(cfg, slots,
+                                                        max_len)))
+    sampler = spec(jax.eval_shape(lambda: sampling.init_state(slots)))
+    toks = jax.ShapeDtypeStruct((slots,), I32, sharding=sharding)
+    return jax.jit(
+        lambda p, t, c, s: lm.decode_steps(p, cfg, t, c, k, sampler=s,
+                                           sample_fn=sampling.sample),
+        donate_argnums=(2, 3)).lower(params, toks, caches,
+                                     sampler).compile().as_text()
+
+
+@pytest.mark.parametrize("arch,layers,slots,max_len", [
+    ("qwen3-next-gdn", 4, 16, 8192), ("mamba2-1.3b", 2, 32, 2048)])
+def test_decode_program_text_unchanged(one_chip, arch, layers, slots,
+                                       max_len):
+    """One period of each benchmarked arch at its cell's slots: the
+    decode tick compiles to the same program as before the Qwen3-Next
+    kinds came into the shared GDN, attention and model code."""
+    text = _normalized(_decode_text(one_chip, arch, layers, slots, max_len))
+    want = json.loads(DECODE_TEXT.read_text())[arch]
+    assert hashlib.sha256(text.encode()).hexdigest() == want
