@@ -8,7 +8,9 @@ Three entry points per layer:
   * ``attn_prefill`` — attn_train + returns the populated KV cache.
   * ``attn_decode``  — one new token against the cache. Pure-JAX einsum path
                        (GSPMD-shardable over batch / heads / cache length) or
-                       the Pallas flash-decode kernel (`use_pallas`).
+                       the Pallas flash-decode kernel (`use_pallas`);
+                       ``attn_decode_stacked`` is the einsum path on one
+                       layer of a layer-stacked cache, writing one row.
 
 KV cache layout: (B, Hkv, Tmax, hd) + scalar lengths (B,).  For SWA archs the
 cache is a rolling buffer of ``window`` positions (O(1) memory at 500k ctx).
@@ -327,14 +329,9 @@ def _cache_insert(cache: KVCache, k_t, v_t):
     return KVCache(new_k, new_v, cache.length + 1)
 
 
-def attn_decode_xla(p, x_t, cache: KVCache, *, rope_theta=10000.0,
-                    window=None, head_mask=None, rotary_dim=None):
-    """One-token decode, pure-JAX (GSPMD-shardable einsum over the cache).
-
-    x_t: (B, d_model). Returns (out (B, d_model), new_cache).
-    """
-    B, d_model = x_t.shape
-    pos = cache.length                    # (B,)
+def _decode_qkv(p, x_t, pos, rope_theta, rotary_dim):
+    """The new token's q (B, Hq, hd), k and v (B, Hkv, hd), rotated to
+    position ``pos`` (B,)."""
     q = jnp.einsum("bd,dhk->bhk", x_t, p["wq"]).astype(x_t.dtype)
     k = jnp.einsum("bd,dhk->bhk", x_t, p["wk"]).astype(x_t.dtype)
     v = jnp.einsum("bd,dhk->bhk", x_t, p["wv"]).astype(x_t.dtype)
@@ -343,8 +340,13 @@ def attn_decode_xla(p, x_t, cache: KVCache, *, rope_theta=10000.0,
                           rotary_dim)[:, 0]
     k = layers.apply_rope(k[:, None], pos[:, None], rope_theta,
                           rotary_dim)[:, 0]
-    cache = _cache_insert(cache, k, v)
+    return q, k, v
 
+
+def _decode_attend(p, x_t, q, cache: KVCache, head_mask):
+    """The new token's output (B, d_model): q against ``cache``, which
+    already holds the token's own k and v."""
+    B = x_t.shape[0]
     size = cache.k.shape[2]
     Hq = q.shape[1]
     Hkv = cache.k.shape[1]
@@ -370,8 +372,46 @@ def attn_decode_xla(p, x_t, cache: KVCache, *, rope_theta=10000.0,
                    preferred_element_type=jnp.float32)
     o = o.reshape(B, Hq, hd).astype(x_t.dtype)
     o = _apply_head_mask(_gate(p, x_t, o), head_mask)
-    out = jnp.einsum("bhk,hkd->bd", o, p["wo"]).astype(x_t.dtype)
-    return out, cache
+    return jnp.einsum("bhk,hkd->bd", o, p["wo"]).astype(x_t.dtype)
+
+
+def attn_decode_xla(p, x_t, cache: KVCache, *, rope_theta=10000.0,
+                    window=None, head_mask=None, rotary_dim=None):
+    """One-token decode, pure-JAX (GSPMD-shardable einsum over the cache).
+
+    x_t: (B, d_model). Returns (out (B, d_model), new_cache).
+    """
+    q, k, v = _decode_qkv(p, x_t, cache.length, rope_theta, rotary_dim)
+    cache = _cache_insert(cache, k, v)
+    return _decode_attend(p, x_t, q, cache, head_mask), cache
+
+
+def attn_decode_stacked(p, x_t, stacked: KVCache, i, *, rope_theta=10000.0,
+                        head_mask=None, rotary_dim=None):
+    """``attn_decode_xla`` on layer ``i`` of a layer-stacked cache (every
+    leaf (L, B, ...)), updated in place: the token's k and v go in as one
+    row per slot at (i, slot, :, length mod size), layer i's length moves
+    by one, and the attention then reads layer i's slice as
+    ``attn_decode_xla`` reads its cache, so the arithmetic is the same.
+    A rolling (windowed) cache is one of ``window`` positions, so the
+    row's slot wraps there.
+
+    Returns (out (B, d_model), stacked)."""
+    length = jax.lax.dynamic_index_in_dim(stacked.length, i, keepdims=False)
+    q, k, v = _decode_qkv(p, x_t, length, rope_theta, rotary_dim)
+    size = stacked.k.shape[3]
+    slot = jnp.mod(length, size)
+    b_idx = jnp.arange(stacked.k.shape[1])
+    stacked = KVCache(
+        stacked.k.at[i, b_idx, :, slot, :].set(k.astype(stacked.k.dtype)),
+        stacked.v.at[i, b_idx, :, slot, :].set(v.astype(stacked.v.dtype)),
+        jax.lax.dynamic_update_index_in_dim(stacked.length, length + 1, i,
+                                            0))
+    layer = KVCache(
+        jax.lax.dynamic_index_in_dim(stacked.k, i, keepdims=False),
+        jax.lax.dynamic_index_in_dim(stacked.v, i, keepdims=False),
+        length + 1)
+    return _decode_attend(p, x_t, q, layer, head_mask), stacked
 
 
 def attn_decode_pallas(p, x_t, cache: KVCache, *, rope_theta=10000.0,

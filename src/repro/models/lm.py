@@ -276,7 +276,14 @@ def _run_cached(params, cfg: ArchConfig, x, caches, mode: str,
                 dp_axes=None, valid_len=None, live=None):
     """The layers over ``caches``.  With ``live`` (decode only) the kinds
     that have decode counters report them over the live rows, summed over
-    layers, and a third value {name: int32} is returned."""
+    layers, and a third value {name: int32} is returned.
+
+    Prefill and chunk scan each group's stacked caches as ``xs`` and
+    return the new ones as ``ys``.  Decode carries them through the layer
+    scan instead and each mixer updates layer r of its stack in place
+    (``decode_stacked``): a fresh ``ys`` would be copied whole into the
+    caller's carry (``decode_steps``' scan) at every step."""
+    decode = mode == "decode"
     groups = build_groups(cfg)
     new_caches = []
     names = decode_counter_names(cfg) if live is not None else ()
@@ -284,8 +291,12 @@ def _run_cached(params, cfg: ArchConfig, x, caches, mode: str,
     for (kinds, reps), gp, gc in zip(groups, params["groups"], caches):
 
         def block(carry, sl, kinds=kinds):
-            x, counts = carry
-            lp_slice, c_slice = sl
+            if decode:
+                x, counts, c_slice = carry
+                r, lp_slice = sl
+            else:
+                x, counts = carry
+                lp_slice, c_slice = sl
             new_c = []
             for i, kind in enumerate(kinds):
                 lp = lp_slice[i]
@@ -299,22 +310,27 @@ def _run_cached(params, cfg: ArchConfig, x, caches, mode: str,
                         mix, nc = mixer.prefill_chunk(lp["mixer"], cfg, h,
                                                       c_slice[i],
                                                       valid_len=valid_len)
-                    elif live is not None and mixer.decode_counters:
-                        mix, nc, c = mixer.decode_counted(
-                            lp["mixer"], cfg, h, c_slice[i], live)
-                        counts = {n: counts[n] + c.get(n, 0)
-                                  for n in counts}
                     else:
-                        mix, nc = mixer.decode(lp["mixer"], cfg, h,
-                                               c_slice[i])
+                        mix, nc, c = mixer.decode_stacked(
+                            lp["mixer"], cfg, h, c_slice[i], r, live)
+                        if c:
+                            counts = {n: counts[n] + c.get(n, 0)
+                                      for n in counts}
                 x = x + mix
                 with jax.named_scope("ffn"):
-                    x, _ = _ffn_fwd(cfg, lp, x, decode=(mode == "decode"))
+                    x, _ = _ffn_fwd(cfg, lp, x, decode=decode)
                 x = _constrain(x, dp_axes)
                 new_c.append(nc)
+            if decode:
+                return (x, counts, new_c), None
             return (x, counts), new_c
 
-        (x, counts), ncs = jax.lax.scan(block, (x, counts), (gp, gc))
+        if decode:
+            (x, counts, ncs), _ = jax.lax.scan(
+                block, (x, counts, list(gc)),
+                (jnp.arange(reps, dtype=jnp.int32), gp))
+        else:
+            (x, counts), ncs = jax.lax.scan(block, (x, counts), (gp, gc))
         new_caches.append(ncs)
     return (x, new_caches) if live is None else (x, new_caches, counts)
 

@@ -75,6 +75,15 @@ class Attention(SequenceMixer):
                                          rotary_dim=cls._rotary_dim(cfg))
 
     @classmethod
+    def decode_stacked(cls, params, cfg, x_t, stacked, i, live=None):
+        # K and V are window-role leaves: a step writes one row per slot
+        # (swa: at its rolling slot, length mod window)
+        out, stacked = attention.attn_decode_stacked(
+            params, x_t, stacked, i, rope_theta=cfg.rope_theta,
+            head_mask=_head_mask(cfg), rotary_dim=cls._rotary_dim(cfg))
+        return out, stacked, {}
+
+    @classmethod
     def cache_spec(cls, cfg, batch, max_len):
         w = cls._window(cfg)
         size = max_len if w is None else min(w, max_len)

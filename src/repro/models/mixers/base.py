@@ -8,6 +8,10 @@ is that claim as an interface.  A mixer kind is one class implementing
   train(params, cfg, x)            -> (B, T, d) mixed output
   prefill(params, cfg, x, cache)   -> ((B, T, d) out, new cache)
   decode(params, cfg, x_t, cache)  -> ((B, d) out, new cache)
+  decode_stacked(params, cfg, x_t, stacked, i, live=None)
+                                   -> decode of layer i of a layer-stacked
+                                      cache, updated in place (default:
+                                      slice, ``decode``, write back whole)
   cache_spec(cfg, batch, max_len)  -> CacheSpec (declarative state layout)
   init_cache(cfg, batch, max_len)  -> cache pytree (default: spec zeros)
 
@@ -183,6 +187,33 @@ class SequenceMixer:
         where ``live`` ((B,) bool) is set: returns (out, cache,
         {name: int32 scalar})."""
         raise NotImplementedError(cls.kind)
+
+    @classmethod
+    def decode_stacked(cls, params, cfg, x_t, stacked, i, live=None):
+        """``decode`` (``decode_counted`` with ``live``, where the kind
+        counts) of layer ``i`` of ``stacked``, this kind's cache with a
+        leading layer axis on every leaf, written back into the stack, so
+        the decode tick carries one buffer through its layer loop and
+        updates it in place.  Returns (out, stacked, {name: int32}), the
+        counters empty where none were asked for.
+
+        Default: slice layer i and write every leaf back whole, which is
+        what a step does to state-role leaves.  A kind with window-role
+        leaves (context-sized buffers a step changes one row of)
+        overrides this to write only that row."""
+        cache = jax.tree.map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, i, keepdims=False),
+            stacked)
+        counts = {}
+        if live is not None and cls.decode_counters:
+            out, cache, counts = cls.decode_counted(params, cfg, x_t, cache,
+                                                    live)
+        else:
+            out, cache = cls.decode(params, cfg, x_t, cache)
+        stacked = jax.tree.map(
+            lambda a, c: jax.lax.dynamic_update_index_in_dim(a, c, i, 0),
+            stacked, cache)
+        return out, stacked, counts
 
     @classmethod
     def cache_spec(cls, cfg, batch: int, max_len: int) -> CacheSpec:

@@ -3,6 +3,9 @@
 Pins the scan-batched tick (``lm.decode_steps``) to the single-step path
 token-for-token and cache-bitwise, and the engine's ``decode_block`` to
 the k=1 engine, so fusing the hot loop can never change what is served.
+The tick updates its stacked caches in place (``decode_stacked``); one
+arch per mixer kind pins that to each layer's own ``decode`` stepped
+over unstacked caches.
 """
 import jax
 import jax.numpy as jnp
@@ -10,7 +13,8 @@ import numpy as np
 import pytest
 
 from repro import configs
-from repro.models import lm
+from repro.models import layers, lm
+from repro.models.mixers import get_mixer
 from repro.serving.engine import DecodeEngine, Request
 
 
@@ -72,6 +76,100 @@ def test_decode_steps_masks_done_slots(gdn_model):
     assert valid[:, 0].all() and not valid[:, 1].any()
     assert (toks[:, 1] == 5).all()           # frozen
     assert int(last[1]) == 5
+
+
+def _per_layer_steps(params, cfg, tokens, caches, k, count):
+    """``k`` greedy decode steps in plain Python: every layer's own
+    ``decode`` (``decode_counted`` over all slots where the kind counts)
+    on its slice of the caches, then the stacks rebuilt.  Returns (tokens
+    (k, B), caches, {counter: (k,) int32})."""
+    groups = lm.build_groups(cfg)
+    act = jnp.dtype(cfg.act_dtype)
+    live = jnp.ones(tokens.shape, bool)
+    # per group, per pattern position: the list of each layer's cache
+    per = [[[jax.tree.map(lambda a, r=r: a[r], gc[i]) for r in range(reps)]
+            for i in range(len(kinds))]
+           for (kinds, reps), gc in zip(groups, caches)]
+    names = lm.decode_counter_names(cfg) if count else ()
+    toks, counts = [], {n: [] for n in names}
+    for _ in range(k):
+        x = layers.embed_fwd(params["embed"], tokens).astype(act)
+        step = {n: jnp.zeros((), jnp.int32) for n in names}
+        for g, ((kinds, reps), gp) in enumerate(zip(groups,
+                                                    params["groups"])):
+            for r in range(reps):
+                for i, kind in enumerate(kinds):
+                    lp = jax.tree.map(lambda a: a[r], gp[i])
+                    mixer = get_mixer(kind)
+                    h = layers.rmsnorm_fwd(lp["norm1"], x, cfg.norm_eps)
+                    if names and mixer.decode_counters:
+                        mix, per[g][i][r], c = mixer.decode_counted(
+                            lp["mixer"], cfg, h, per[g][i][r], live)
+                        step = {n: step[n] + c[n] for n in names}
+                    else:
+                        mix, per[g][i][r] = mixer.decode(
+                            lp["mixer"], cfg, h, per[g][i][r])
+                    x, _ = lm._ffn_fwd(cfg, lp, x + mix, decode=True)
+        h = layers.rmsnorm_fwd(params["final_norm"], x, cfg.norm_eps)
+        tokens = jnp.argmax(lm._logits(params, cfg, h),
+                            -1).astype(jnp.int32)
+        toks.append(tokens)
+        for n in names:
+            counts[n].append(step[n])
+    stacked = [[jax.tree.map(lambda *a: jnp.stack(a), *layer_caches)
+                for layer_caches in g] for g in per]
+    return (jnp.stack(toks), stacked,
+            {n: jnp.stack(v) for n, v in counts.items()})
+
+
+# one arch per mixer kind; "swa" and recurrentgemma's swa get a window of
+# 4 so the 8-token prompt and the 6 steps wrap the rolling slot
+CARRY_ARCHS = {"attn": "yi-9b", "swa": "h2o-danube-1.8b",
+               "gdn": "qwen3-next-gdn", "ssm": "mamba2-1.3b",
+               "rglru": "recurrentgemma-2b", "qnext": "qwen3-next-80b-a3b"}
+
+
+@pytest.mark.parametrize("kind", sorted(CARRY_ARCHS))
+def test_decode_steps_in_place_matches_per_layer_decode(kind):
+    """``decode_steps`` carries the stacked caches through its layer
+    scan and writes each layer's update into them (K/V one row per
+    slot): bitwise the tokens, caches and counters of each layer's
+    per-layer ``decode`` over unstacked caches.  Two full periods and a
+    partial one, so the layer index runs over repeats and groups."""
+    cfg = configs.get_arch(CARRY_ARCHS[kind]).reduced()
+    cfg = cfg.replace(n_layers=2 * len(cfg.pattern) + 1)
+    if cfg.window:
+        cfg = cfg.replace(window=4)
+    B, T, k = 2, 8, 6
+    count = bool(lm.decode_counter_names(cfg))
+    params = lm.init_lm(jax.random.PRNGKey(0), cfg)
+    prompt = jax.random.randint(jax.random.PRNGKey(1), (B, T), 0, cfg.vocab)
+    logits, caches = lm.prefill(params, cfg, lm.init_caches(cfg, B, 32),
+                                tokens=prompt)
+    first = jnp.argmax(logits, -1).astype(jnp.int32)
+
+    want_toks, want_caches, want_counts = jax.jit(
+        _per_layer_steps, static_argnums=(1, 4, 5))(
+        params, cfg, first, caches, k, count)
+    out = jax.jit(lm.decode_steps, static_argnums=(1, 4),
+                  static_argnames=("count",))(
+        params, cfg, first, caches, k, count=count)
+    toks, _, last, got_caches = out[:4]
+
+    np.testing.assert_array_equal(np.asarray(toks), np.asarray(want_toks))
+    np.testing.assert_array_equal(np.asarray(last),
+                                  np.asarray(want_toks[-1]))
+    assert (jax.tree.structure(got_caches)
+            == jax.tree.structure(want_caches))
+    for a, b in zip(jax.tree.leaves(got_caches),
+                    jax.tree.leaves(want_caches)):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    if count:
+        assert set(out[5]) == set(want_counts)
+        for n in want_counts:
+            np.testing.assert_array_equal(np.asarray(out[5][n]),
+                                          np.asarray(want_counts[n]))
 
 
 def _engine_outputs(cfg, params, k, *, stochastic=False, eos=None):

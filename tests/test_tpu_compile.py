@@ -8,10 +8,11 @@ compiler for a described, unattached ``v5e:2x2`` chip — no device runs
 anything — at the widths of qwen3-next-gdn (GDN B=4, Hk=16, Hv=32,
 d=128; attention Hq=16, Hkv=2, d=128) and mamba2-1.3b (the
 ``delta_rule=False`` path: Hk=1, Hv=64, d_k=128, d_v=64).  The last
-test compiles mamba2-1.3b's whole decode tick and reads the layout the
-compiler gave its SSD state, and the decode ticks of qwen3-next-gdn and
-mamba2-1.3b are compared with the text the compiler gave them before the
-``qnext_*`` mixer kinds shared their code.
+tests compile whole decode ticks: mamba2-1.3b's, for the layout the
+compiler gave its SSD state; qwen3-next-gdn's and mamba2-1.3b's, against
+the text recorded in ``golden/decode_text.json``; and both qwen3-next
+archs', for stacked caches updated in place (no whole-cache copy in a
+loop, one K/V row written per slot).
 
 The topology is described inside a module fixture, never at import:
 only one process may load the TPU library, and pytest-xdist workers all
@@ -19,6 +20,7 @@ import this file.
 """
 import hashlib
 import json
+import math
 import os
 import re
 from pathlib import Path
@@ -115,6 +117,31 @@ def test_attn_decode_compiles(one_chip, window):
         ((B,), I32))
 
 
+def _decode_tick(sharding, arch, layers, slots, max_len, k=4, count=False):
+    """The serving decode tick (``decode_steps``, caches and sampler
+    donated) of ``arch`` at ``layers`` layers, compiled, and the shapes
+    of its cache leaves."""
+    cfg = configs.get_arch(arch).replace(n_layers=layers)
+
+    def spec(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=sharding), tree)
+
+    params = spec(jax.eval_shape(lambda key: lm.init_lm(key, cfg),
+                                 jax.random.PRNGKey(0)))
+    caches = spec(jax.eval_shape(lambda: lm.init_caches(cfg, slots,
+                                                        max_len)))
+    sampler = spec(jax.eval_shape(lambda: sampling.init_state(slots)))
+    toks = jax.ShapeDtypeStruct((slots,), I32, sharding=sharding)
+    compiled = jax.jit(
+        lambda p, t, c, s: lm.decode_steps(p, cfg, t, c, k, sampler=s,
+                                           sample_fn=sampling.sample,
+                                           count=count),
+        donate_argnums=(2, 3)).lower(params, toks, caches,
+                                     sampler).compile()
+    return compiled, caches
+
+
 def _f32_results(hlo: str, op: str, dims):
     """(name, shape, layout) of every ``op`` instruction whose f32 result
     holds ``dims`` in any order."""
@@ -133,24 +160,8 @@ def test_mamba2_decode_state_layout(one_chip):
     the SSD state in its stored layout, with d_state = 128 on the lanes:
     no whole-state transpose copy, every fusion writing the state has 128
     minor, and no temporary of the state's size."""
-    cfg = configs.get_arch("mamba2-1.3b").replace(n_layers=2)
-    slots, max_len, k = 32, 2048, 4
-
-    def spec(tree):
-        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
-            a.shape, a.dtype, sharding=one_chip), tree)
-
-    params = spec(jax.eval_shape(lambda key: lm.init_lm(key, cfg),
-                                 jax.random.PRNGKey(0)))
-    caches = spec(jax.eval_shape(lambda: lm.init_caches(cfg, slots,
-                                                        max_len)))
-    sampler = spec(jax.eval_shape(lambda: sampling.init_state(slots)))
-    toks = jax.ShapeDtypeStruct((slots,), I32, sharding=one_chip)
-    compiled = jax.jit(
-        lambda p, t, c, s: lm.decode_steps(p, cfg, t, c, k, sampler=s,
-                                           sample_fn=sampling.sample),
-        donate_argnums=(2, 3)).lower(params, toks, caches,
-                                     sampler).compile()
+    cfg = configs.get_arch("mamba2-1.3b")
+    compiled, caches = _decode_tick(one_chip, "mamba2-1.3b", 2, 32, 2048)
     hlo = compiled.as_text()
     S = caches[0][0].S
     assert S.dtype == F32 and S.shape[-1] == cfg.ssm_d_state == 128
@@ -165,7 +176,7 @@ def test_mamba2_decode_state_layout(one_chip):
 
 
 # sha256 of the normalized decode-tick text (``_decode_text``), compiled
-# at the parent of the change that added the ``qwen3-next-80b-a3b`` kinds
+# when the decode tick came to update its stacked caches in place
 DECODE_TEXT = Path(__file__).parent / "golden" / "decode_text.json"
 
 
@@ -182,23 +193,8 @@ def _normalized(hlo: str) -> str:
 
 
 def _decode_text(sharding, arch, layers, slots, max_len, k=4):
-    cfg = configs.get_arch(arch).replace(n_layers=layers)
-
-    def spec(tree):
-        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
-            a.shape, a.dtype, sharding=sharding), tree)
-
-    params = spec(jax.eval_shape(lambda key: lm.init_lm(key, cfg),
-                                 jax.random.PRNGKey(0)))
-    caches = spec(jax.eval_shape(lambda: lm.init_caches(cfg, slots,
-                                                        max_len)))
-    sampler = spec(jax.eval_shape(lambda: sampling.init_state(slots)))
-    toks = jax.ShapeDtypeStruct((slots,), I32, sharding=sharding)
-    return jax.jit(
-        lambda p, t, c, s: lm.decode_steps(p, cfg, t, c, k, sampler=s,
-                                           sample_fn=sampling.sample),
-        donate_argnums=(2, 3)).lower(params, toks, caches,
-                                     sampler).compile().as_text()
+    return _decode_tick(sharding, arch, layers, slots, max_len,
+                        k)[0].as_text()
 
 
 @pytest.mark.parametrize("arch,layers,slots,max_len", [
@@ -206,8 +202,97 @@ def _decode_text(sharding, arch, layers, slots, max_len, k=4):
 def test_decode_program_text_unchanged(one_chip, arch, layers, slots,
                                        max_len):
     """One period of each benchmarked arch at its cell's slots: the
-    decode tick compiles to the same program as before the Qwen3-Next
-    kinds came into the shared GDN, attention and model code."""
+    decode tick compiles to the program recorded in the golden, which a
+    change to the shared GDN, attention or model code must leave as it
+    is, or regenerate (``tests/golden/README.md``)."""
     text = _normalized(_decode_text(one_chip, arch, layers, slots, max_len))
     want = json.loads(DECODE_TEXT.read_text())[arch]
     assert hashlib.sha256(text.encode()).hexdigest() == want
+
+
+_INSTR = re.compile(r"^\s*(?:ROOT )?%(\S+) = \w+\[([\d,]*)\]\{[^}]*\} "
+                    r"([\w\-]+)\((.*)$")
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%(\S+) \(.*\{$")
+
+
+def _computations(hlo: str):
+    """{computation name: its instruction lines}."""
+    comps, cur = {}, None
+    for line in hlo.splitlines():
+        m = _COMPUTATION.match(line)
+        if m:
+            cur = comps.setdefault(m.group(1), [])
+        elif cur is not None and line.strip() not in ("", "}"):
+            cur.append(line)
+    return comps
+
+
+def _instructions(lines):
+    """(name, shape, opcode, operand names) of each array instruction."""
+    for line in lines:
+        m = _INSTR.match(line)
+        if m:
+            name, shape, op, rest = m.groups()
+            yield (name, tuple(int(d) for d in shape.split(",") if d), op,
+                   re.findall(r"%([\w.\-]+)", rest.split(")")[0]))
+
+
+def _in_loops(comps):
+    """The names of every while-loop body and what it calls, nested loop
+    bodies and fused computations included."""
+    todo = [b for lines in comps.values() for line in lines
+            for b in re.findall(r"\bbody=%([\w.\-]+)", line)]
+    seen = set()
+    while todo:
+        c = todo.pop()
+        if c in seen or c not in comps:
+            continue
+        seen.add(c)
+        for line in comps[c]:
+            todo += re.findall(
+                r"(?:calls|body|condition|to_apply)=%([\w.\-]+)", line)
+            for group in re.findall(r"called_computations=\{([^}]*)\}",
+                                    line):
+                todo += re.findall(r"%([\w.\-]+)", group)
+    return seen
+
+
+@pytest.mark.parametrize("arch,layers,slots,max_len,count,parent_temp", [
+    ("qwen3-next-gdn", 8, 16, 8192, False, 1_042_673_664),
+    ("qwen3-next-80b-a3b", 16, 16, 4096, True, 1_014_640_640)])
+def test_decode_updates_caches_in_place(one_chip, arch, layers, slots,
+                                        max_len, count, parent_temp):
+    """Two periods of each qwen3-next arch at its cell's slots, the decode
+    tick as the executor serves it: the stacked caches are carried
+    through the layer loop and updated in place.  No copy inside any loop
+    has a stacked cache leaf's shape (a scanned ``ys`` copied back into
+    the step loop's carry did, once per leaf per step); every write into
+    a stacked K or V leaf has a row per slot as its update (not a whole
+    layer); and the temporaries are under half of what the ``xs``/``ys``
+    layer scan needed (``parent_temp``, compiled the same way)."""
+    compiled, _ = _decode_tick(one_chip, arch, layers, slots, max_len,
+                               count=count)
+    cfg = configs.get_arch(arch).replace(n_layers=layers)
+    spec = lm.cache_specs(cfg, slots, max_len).leaves()
+    stacked = {tuple(sorted(a.shape)) for a in spec if a.role != "meta"}
+    kv = [a.shape for a in spec if a.role == "window"]
+    assert kv, "no stacked K or V leaf"
+    comps = _computations(compiled.as_text())
+    loops = _in_loops(comps)
+    assert loops, "the decode tick has no loop"
+    shapes = {name: shape for lines in comps.values()
+              for name, shape, _, _ in _instructions(lines)}
+    writes = 0
+    for c, lines in comps.items():
+        for name, shape, op, operands in _instructions(lines):
+            if op == "copy" and c in loops:
+                assert tuple(sorted(shape)) not in stacked, (c, name, shape)
+            if op in ("dynamic-update-slice", "scatter") and shape in kv:
+                # (layers, slots, Hkv, T, hd): a row is (slots, Hkv, hd)
+                row = shape[1] * shape[2] * shape[4]
+                update = operands[1 if op == "dynamic-update-slice" else -1]
+                assert math.prod(shapes[update]) <= row, (
+                    name, shape, shapes[update])
+                writes += 1
+    assert writes >= len(kv), "K and V are not written in the tick"
+    assert compiled.memory_analysis().temp_size_in_bytes < parent_temp / 2
